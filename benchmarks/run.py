@@ -60,7 +60,11 @@ def main() -> None:
         os.environ["REPRO_BENCH_QUICK"] = "1"
     suites = [args.only] if args.only else list(SUITES)
 
+    from repro.compile_cache import use_compile_cache
+
     from . import common
+
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     t0 = time.time()

@@ -1,30 +1,21 @@
-"""Shims over jax API drift so the repo runs on both old and new releases.
+"""Thin names over the installed jax API, so call sites stay version-neutral.
 
-jax moved `shard_map` from `jax.experimental` to the top level, added
-`jax.lax.pvary`, and changed `Compiled.cost_analysis()` from a list of
-per-computation dicts to a single dict.  Every call site routes through here
-instead of version-checking locally.
+`shard_map` is jax's top-level one; `pvary` tags a replicated value as
+device-varying inside it (`jax.lax.pcast(..., to="varying")`); and
+`cost_analysis_dict` reads `Compiled.cost_analysis()`, which may be None.
 """
 from __future__ import annotations
 
 import jax
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
 
 
 def pvary(x, axis_names):
-    """`jax.lax.pvary` where available; identity otherwise (older jax treats
-    unvaried replicated values implicitly, so no tagging is needed)."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axis_names) if fn is not None else x
+    """Mark `x` as varying over `axis_names` inside `shard_map`."""
+    return jax.lax.pcast(x, tuple(axis_names), to="varying")
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """`Compiled.cost_analysis()` as a flat dict on every jax version."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        return ca[0] if ca else {}
-    return ca or {}
+    """`Compiled.cost_analysis()` as a dict (empty when XLA gives none)."""
+    return compiled.cost_analysis() or {}

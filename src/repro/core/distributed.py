@@ -8,8 +8,9 @@ striding balances each device's pair count to within n/2 pairs (the same
 load-balancing concern the paper solves with its eq. 49/50 block-index math —
 here solved by ownership pattern instead, no index arithmetic needed).
 
-The sample x is *replicated* (O(n) bytes; even n=4M fp32 is 16 MB — trivial
-against 95 GB HBM), each device reduces its own rows with the same chunked
+The sample x is *replicated* (O(n) bytes; even n=4M fp32 is 16 MB — small
+against a TPU v5e chip's 16 GB HBM), each device reduces its own rows with
+the same chunked
 slab computation used on a single chip, and a single `psum` produces the global
 sum — one small scalar/vector collective per reduction, which is why these
 selectors scale to a full pod essentially linearly (see EXPERIMENTS.md §Perf).
@@ -28,6 +29,9 @@ from repro import compat
 from . import gaussian as G
 from .lscv import h_grid_for
 from .reductions import pairwise_reduce
+
+# f32 matmul passes: XLA on a TPU defaults f32 dots to one bf16 pass
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _strided_pairwise_partial(fun: Callable, x: jax.Array, p: jax.Array, n_dev: int,
@@ -105,7 +109,7 @@ def sharded_lscv_h_grid(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
         nsteps = -(-rows_per_dev // c)
         cols = jnp.arange(n)
         if algorithm == "mxu":
-            mx = x_rep @ sigma_inv                       # (n, d), hoisted
+            mx = jnp.dot(x_rep, sigma_inv, precision=_F32)   # (n, d), hoisted
             qx = jnp.sum(mx * x_rep, axis=1)             # (n,)
 
         def body(acc, r):
@@ -114,13 +118,14 @@ def sharded_lscv_h_grid(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
             ok = row_idx < n
             rows = jnp.take(x_rep, jnp.where(ok, row_idx, 0), axis=0)
             if algorithm == "mxu":
-                mr = rows @ sigma_inv                     # (c, d)
+                mr = jnp.dot(rows, sigma_inv, precision=_F32)     # (c, d)
                 qr = jnp.sum(mr * rows, axis=1)           # (c,)
-                cross = mr @ x_rep.T                      # (c, n) on the MXU
+                cross = jnp.dot(mr, x_rep.T, precision=_F32)  # (c, n), MXU
                 s = qr[:, None] + qx[None, :] - 2.0 * cross
             else:
                 v = rows[:, None, :] - x_rep[None, :, :]
-                s = jnp.einsum("rnd,de,rne->rn", v, sigma_inv, v)
+                s = jnp.einsum("rnd,de,rne->rn", v, sigma_inv, v,
+                               precision=_F32)
             mask = (ok[:, None] & (row_idx[:, None] < cols[None, :])).astype(s.dtype)
             sm = s * mask
 

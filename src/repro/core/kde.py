@@ -90,7 +90,8 @@ def _kde_eval_H(points: jax.Array, x: jax.Array, H: jax.Array, chunk: int) -> ja
     log_norm = -d / 2.0 * math.log(2.0 * math.pi) - 0.5 * logdet
 
     def kfun(diff):
-        quad = 0.5 * jnp.einsum("cnd,de,cne->cn", diff, H_inv, diff)
+        quad = 0.5 * jnp.einsum("cnd,de,cne->cn", diff, H_inv, diff,
+                                precision=jax.lax.Precision.HIGHEST)
         return jnp.exp(log_norm - quad)
 
     return _chunked_eval(points, x, kfun, chunk)

@@ -40,8 +40,11 @@ def covariance(x: jax.Array) -> jax.Array:
     rows, the JAX-native layout).  Returns (d, d) Sigma per eqs. (22)/(23)."""
     n = x.shape[0]
     s1 = jnp.sum(x, axis=0)                       # (d,)
-    s2 = x.T @ x                                  # (d, d) sum of outer products
-    return s2 / (n - 1) - jnp.outer(s1, s1) / (n * (n - 1))
+    # (d, d) sum of outer products, in f32: a bf16 pass would cost the
+    # covariance (and every LSCV objective built on it) ~3 digits on a TPU
+    s2 = jnp.dot(x.T, x, precision=jax.lax.Precision.HIGHEST)
+    # float denominators: n (n - 1) overflows int32 from n = 46,341
+    return s2 / (n - 1.0) - jnp.outer(s1, s1) / (n * (n - 1.0))
 
 
 def h0_start(n: int, d: int) -> float:
@@ -206,7 +209,8 @@ def g_of_H(x: jax.Array, H: jax.Array, chunk: int = 128, backend: str = "jnp") -
 def matrix_sqrt(a: jax.Array) -> jax.Array:
     """SPD matrix square root via eigendecomposition (paper uses ALGLIB)."""
     w, v = jnp.linalg.eigh(a)
-    return (v * jnp.sqrt(jnp.clip(w, 0.0))) @ v.T
+    return jnp.dot(v * jnp.sqrt(jnp.clip(w, 0.0)), v.T,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def h_start(x: jax.Array) -> jax.Array:
@@ -225,7 +229,7 @@ def _theta_to_H(theta: jax.Array, d: int) -> jax.Array:
     il, jl = _vech_indices(d)
     L = jnp.zeros((d, d), theta.dtype).at[il, jl].set(theta)
     L = L.at[jnp.diag_indices(d)].set(jnp.exp(jnp.diagonal(L)))
-    return L @ L.T
+    return jnp.dot(L, L.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def _H_to_theta(H: jax.Array) -> jax.Array:

@@ -34,7 +34,8 @@ def variance_estimator(x: jax.Array) -> jax.Array:
     n = x.shape[0]
     s2 = reduce_sum(x * x)
     s1 = reduce_sum(x)
-    return s2 / (n - 1) - (s1 * s1) / (n * (n - 1))
+    # float denominators: n (n - 1) overflows int32 from n = 46,341
+    return s2 / (n - 1.0) - (s1 * s1) / (n * (n - 1.0))
 
 
 def _psi_r(pair_sum: jax.Array, k_at_0: float, n: int, g: jax.Array, r: int) -> jax.Array:
@@ -43,7 +44,8 @@ def _psi_r(pair_sum: jax.Array, k_at_0: float, n: int, g: jax.Array, r: int) -> 
     This is eqs. (16)/(18) with the diagonal written explicitly: the full
     double sum over (i,j) has n diagonal K^(r)(0) terms and twice the i<j sum.
     """
-    return (2.0 * pair_sum + n * k_at_0) / (n * n * g ** (r + 1))
+    # float n: n * n overflows int32 from n = 46,341
+    return (2.0 * pair_sum + n * k_at_0) / (float(n) * n * g ** (r + 1))
 
 
 @partial(jax.jit, static_argnames=("chunk", "backend"))

@@ -121,7 +121,8 @@ def pairwise_quadform_chunks(x: jax.Array, m: jax.Array, chunk: int = 128):
             row_idx = r * c + jnp.arange(c)
             rows = jax.lax.dynamic_slice_in_dim(xp, r * c, c)
             v = rows[:, None, :] - xp[None, :, :]             # (c, n_pad, d)
-            s = jnp.einsum("rnd,de,rne->rn", v, m, v)         # quadratic forms
+            s = jnp.einsum("rnd,de,rne->rn", v, m, v,         # quadratic forms
+                           precision=jax.lax.Precision.HIGHEST)
             mask = (row_idx[:, None] < cols[None, :]) & (cols[None, :] < n) & (row_idx[:, None] < n)
             return consume(acc, s, mask), None
 

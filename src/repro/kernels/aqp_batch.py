@@ -15,38 +15,34 @@ kernel stays a pure two-channel reduction.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .tuning import resolve_tile
+from .erf import phi_terms
+from .tuning import lane_tile, resolve_tile, row_tile
 
 # Defaults; resolved per CALL against REPRO_AQP_TILE / REPRO_AQP_Q_TILE so a
 # sweep or late env change moves them without a restart; kwargs still win.
 TILE = 256
 Q_TILE = 128
 
-_SQRT1_2 = 1.0 / math.sqrt(2.0)
-_INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
 
-
-def _kernel(a_ref, b_ref, x_ref, h_ref, out_ref, *, n: int, qk: int, k: int):
+def _kernel(a_ref, b_ref, x_ref, h_ref, out_ref, *, n: int, k: int):
     j = pl.program_id(1)   # data-tile index (minor: varies fastest)
-    a = a_ref[...]         # (qk,) lower range bounds
-    b = b_ref[...]         # (qk,) upper range bounds
-    x = x_ref[...]         # (k,) sample chunk (padded entries masked below)
-    h = h_ref[0]
+    a = a_ref[...]         # (qk, 1) lower range bounds
+    b = b_ref[...]         # (qk, 1) upper range bounds
+    x = x_ref[...]         # (1, k) sample chunk (padded entries masked below)
+    h = h_ref[0]           # scalar bandwidth (SMEM)
     inv_h = 1.0 / h
 
-    za = (a[:, None] - x[None, :]) * inv_h              # (qk, k)
-    zb = (b[:, None] - x[None, :]) * inv_h
-    d_Phi = 0.5 * (jax.scipy.special.erf(zb * _SQRT1_2)
-                   - jax.scipy.special.erf(za * _SQRT1_2))
-    d_phi = _INV_SQRT_2PI * (jnp.exp(-0.5 * zb * zb) - jnp.exp(-0.5 * za * za))
+    za = (a - x) * inv_h                                # (qk, k)
+    zb = (b - x) * inv_h
+    d_Phi, d_phi = phi_terms(za, zb)
 
-    cols = j * k + jax.lax.broadcasted_iota(jnp.int32, (qk, k), 1)
+    cols = j * k + jax.lax.broadcasted_iota(jnp.int32, za.shape, 1)
     valid = cols < n
     d_Phi = jnp.where(valid, d_Phi, 0.0)
     d_phi = jnp.where(valid, d_phi, 0.0)
@@ -55,9 +51,8 @@ def _kernel(a_ref, b_ref, x_ref, h_ref, out_ref, *, n: int, qk: int, k: int):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    cnt = jnp.sum(d_Phi, axis=1)
-    sm = jnp.sum(x[None, :] * d_Phi - h * d_phi, axis=1)
-    out_ref[...] += jnp.stack([cnt, sm], axis=1)        # (qk, 2)
+    out_ref[:, 0:1] += jnp.sum(d_Phi, axis=1, keepdims=True)
+    out_ref[:, 1:2] += jnp.sum(x * d_Phi - h * d_phi, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "q_tile", "interpret"))
@@ -69,20 +64,22 @@ def _aqp_batch_sums(x, h, a, b, tile, q_tile, interpret):
         z = jnp.zeros((q,), x.dtype)
         return z, z
 
-    k = min(tile, max(8, 1 << (n - 1).bit_length()))
-    qk = min(q_tile, max(8, 1 << (q - 1).bit_length()))
-    xp = jnp.pad(x, (0, (-n) % k))
-    ap = jnp.pad(a, (0, (-q) % qk))
-    bp = jnp.pad(b, (0, (-q) % qk))
+    # Layout: queries on sublanes, samples on lanes — every operand is 2-D,
+    # so each block is (8, 128)-tileable or spans its whole array.
+    k = lane_tile(tile, n)
+    qk = row_tile(q_tile, q)
+    xp = jnp.pad(x, (0, (-n) % k)).reshape(1, -1)
+    ap = jnp.pad(a, (0, (-q) % qk)).reshape(-1, 1)
+    bp = jnp.pad(b, (0, (-q) % qk)).reshape(-1, 1)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, n=n, qk=qk, k=k),
-        grid=(ap.shape[0] // qk, xp.shape[0] // k),
+        functools.partial(_kernel, n=n, k=k),
+        grid=(ap.shape[0] // qk, xp.shape[1] // k),
         in_specs=[
-            pl.BlockSpec((qk,), lambda i, j: (i,)),
-            pl.BlockSpec((qk,), lambda i, j: (i,)),
-            pl.BlockSpec((k,), lambda i, j: (j,)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            pl.BlockSpec((qk, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((qk, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, k), lambda i, j: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((qk, 2), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((ap.shape[0], 2), x.dtype),

@@ -11,9 +11,9 @@ SUM/AVG target axis t_q) and sample row x_i it accumulates
 
 Grid: (query-tile major, data-tile minor) — the (qk, 2) accumulator block
 stays resident while data tiles stream through, the same pattern as
-aqp_batch.py.  The dims axis stays whole inside the block (d is small for
-box predicates), so the per-axis select-and-product runs entirely in
-registers/VMEM.  COUNT/SUM/AVG selection and the sample->relation scale are
+aqp_batch.py.  The dims axis is unrolled (d is small for box predicates),
+so the per-axis select-and-product runs on (queries x samples) slabs in
+VMEM.  COUNT/SUM/AVG selection and the sample->relation scale are
 applied by the caller (core/aqp_multid.py); the kernel is a pure two-channel
 reduction.
 
@@ -23,57 +23,53 @@ see tuning.resolve_tile); call-site kwargs win.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .tuning import resolve_tile
+from .erf import phi_terms
+from .tuning import lane_tile, resolve_tile, row_tile
 
 TILE = 128     # default (env: REPRO_AQP_BOXES_TILE)
 Q_TILE = 64    # default (env: REPRO_AQP_BOXES_Q_TILE)
 
-_SQRT1_2 = 1.0 / math.sqrt(2.0)
-_INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
-
-
 def _kernel(lo_ref, hi_ref, tgt_ref, x_ref, h_ref, out_ref,
-            *, n: int, qk: int, k: int, d: int):
+            *, n: int, k: int, d: int):
     j = pl.program_id(1)     # data-tile index (minor: varies fastest)
     lo = lo_ref[...]         # (qk, d) box lower corners
     hi = hi_ref[...]         # (qk, d) box upper corners
-    tgt = tgt_ref[...]       # (qk,)  SUM/AVG target axis per query
-    x = x_ref[...]           # (k, d) sample rows (padded rows masked below)
-    h = h_ref[...]           # (d,)   diagonal bandwidth
-    inv_h = 1.0 / h
+    tgt = tgt_ref[...]       # (qk, 1) SUM/AVG target axis per query
+    x = x_ref[...]           # (d, k) sample rows, transposed (padding masked)
 
-    za = (lo[:, None, :] - x[None, :, :]) * inv_h[None, None, :]   # (qk, k, d)
-    zb = (hi[:, None, :] - x[None, :, :]) * inv_h[None, None, :]
-    d_Phi = 0.5 * (jax.scipy.special.erf(zb * _SQRT1_2)
-                   - jax.scipy.special.erf(za * _SQRT1_2))
-    d_phi = _INV_SQRT_2PI * (jnp.exp(-0.5 * zb * zb) - jnp.exp(-0.5 * za * za))
-    moment = x[None, :, :] * d_Phi - h[None, None, :] * d_phi
+    # d is small for box predicates: unroll it, so every intermediate is a
+    # (queries x samples) slab.  SUM factors: axis t_q carries the
+    # first-moment term, every other axis its Phi difference — a select
+    # beats dividing the full product by dPhi_t, which blows up when a box
+    # edge leaves ~zero mass on an axis.
+    cnt_i = sum_i = None
+    for a in range(d):
+        h = h_ref[a]                                   # SMEM scalar
+        xa = x[a:a + 1, :]                             # (1, k)
+        za = (lo[:, a:a + 1] - xa) * (1.0 / h)         # (qk, k)
+        zb = (hi[:, a:a + 1] - xa) * (1.0 / h)
+        d_Phi, d_phi = phi_terms(za, zb)
+        factor = jnp.where(tgt == a, xa * d_Phi - h * d_phi, d_Phi)
+        cnt_i = d_Phi if cnt_i is None else cnt_i * d_Phi
+        sum_i = factor if sum_i is None else sum_i * factor
 
-    # SUM factors: axis t_q carries the first-moment term, every other axis
-    # its Phi difference — a select beats dividing the full product by
-    # dPhi_t, which blows up when a box edge leaves ~zero mass on an axis.
-    axis = jax.lax.broadcasted_iota(jnp.int32, (1, 1, d), 2)
-    factors = jnp.where(axis == tgt[:, None, None], moment, d_Phi)
-
-    cnt_i = jnp.prod(d_Phi, axis=2)                    # (qk, k)
-    sum_i = jnp.prod(factors, axis=2)
-
-    rows = j * k + jax.lax.broadcasted_iota(jnp.int32, (qk, k), 1)
+    rows = j * k + jax.lax.broadcasted_iota(jnp.int32, cnt_i.shape, 1)
     valid = rows < n
-    cnt = jnp.sum(jnp.where(valid, cnt_i, 0.0), axis=1)
-    sm = jnp.sum(jnp.where(valid, sum_i, 0.0), axis=1)
 
     @pl.when(j == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += jnp.stack([cnt, sm], axis=1)       # (qk, 2)
+    out_ref[:, 0:1] += jnp.sum(jnp.where(valid, cnt_i, 0.0), axis=1,
+                               keepdims=True)
+    out_ref[:, 1:2] += jnp.sum(jnp.where(valid, sum_i, 0.0), axis=1,
+                               keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "q_tile", "interpret"))
@@ -85,27 +81,28 @@ def _aqp_box_sums(x, h_diag, lo, hi, tgt, tile, q_tile, interpret):
         z = jnp.zeros((q,), x.dtype)
         return z, z
 
-    k = min(tile, max(8, 1 << (n - 1).bit_length()))
-    qk = min(q_tile, max(8, 1 << (q - 1).bit_length()))
-    xp = jnp.pad(x, ((0, (-n) % k), (0, 0)))
+    # Layout: queries on sublanes, samples on lanes (x transposed to (d, n)).
+    k = lane_tile(tile, n)
+    qk = row_tile(q_tile, q)
+    xt = jnp.pad(x, ((0, (-n) % k), (0, 0))).T
     lop = jnp.pad(lo, ((0, (-q) % qk), (0, 0)))
     hip = jnp.pad(hi, ((0, (-q) % qk), (0, 0)))
-    tgtp = jnp.pad(tgt, (0, (-q) % qk))
+    tgtp = jnp.pad(tgt.astype(jnp.int32), (0, (-q) % qk)).reshape(-1, 1)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, n=n, qk=qk, k=k, d=d),
-        grid=(lop.shape[0] // qk, xp.shape[0] // k),
+        functools.partial(_kernel, n=n, k=k, d=d),
+        grid=(lop.shape[0] // qk, xt.shape[1] // k),
         in_specs=[
             pl.BlockSpec((qk, d), lambda i, j: (i, 0)),
             pl.BlockSpec((qk, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((qk,), lambda i, j: (i,)),
-            pl.BlockSpec((k, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
+            pl.BlockSpec((qk, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((d, k), lambda i, j: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((qk, 2), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((lop.shape[0], 2), x.dtype),
         interpret=interpret,
-    )(lop, hip, tgtp, xp, h_diag.astype(x.dtype))
+    )(lop, hip, tgtp, xt, h_diag.astype(x.dtype))
     return out[:q, 0], out[:q, 1]
 
 

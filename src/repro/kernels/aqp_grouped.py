@@ -18,7 +18,7 @@ query aggregates the group column itself (`tgt_is_group`).
 
 Grid: (category-tile major, data-tile minor) — the (gk, 2) accumulator
 block stays resident while data tiles stream through, and the per-tile
-cross term is a (gk, k) @ (k,) matvec on the MXU.  COUNT/SUM/AVG selection
+cross term is a (gk, k) slab times the shared (1, k) row, summed over lanes.  COUNT/SUM/AVG selection
 and the sample->relation scale are applied by the caller
 (core/aqp_multid.py); the kernel is a pure two-channel reduction.
 
@@ -28,71 +28,60 @@ REPRO_AQP_GROUPED_G_TILE, see tuning.resolve_tile); call-site kwargs win.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .tuning import resolve_tile
+from .erf import phi_terms
+from .tuning import lane_tile, resolve_tile, row_tile
 
 TILE = 128     # data-tile default (env: REPRO_AQP_GROUPED_TILE)
 G_TILE = 64    # category-tile default (env: REPRO_AQP_GROUPED_G_TILE)
 
-_SQRT1_2 = 1.0 / math.sqrt(2.0)
-_INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
-
-
 def _kernel(glo_ref, ghi_ref, x_ref, h_ref, lo_ref, hi_ref, out_ref,
-            *, n: int, gk: int, k: int, d: int, g_axis: int, tgt: int,
-            tgt_is_group: bool):
+            *, n: int, k: int, d: int, g_axis: int, tgt: int):
     j = pl.program_id(1)     # data-tile index (minor: varies fastest)
-    glo = glo_ref[...]       # (gk,) per-category window on the group axis
+    glo = glo_ref[...]       # (gk, 1) per-category window on the group axis
     ghi = ghi_ref[...]
-    x = x_ref[...]           # (k, d) sample rows (padded rows masked below)
-    h = h_ref[...]           # (d,)   diagonal bandwidth
-    lo = lo_ref[...]         # (d,)   shared box (group axis' entry ignored)
-    hi = hi_ref[...]
+    x = x_ref[...]           # (d, k) sample rows, transposed (padding masked)
 
-    inv_h = 1.0 / h
-    za = (lo[None, :] - x) * inv_h[None, :]            # (k, d)
-    zb = (hi[None, :] - x) * inv_h[None, :]
-    d_Phi = 0.5 * (jax.scipy.special.erf(zb * _SQRT1_2)
-                   - jax.scipy.special.erf(za * _SQRT1_2))
-    axis = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
-    keep = axis != g_axis
-    shared_cnt = jnp.prod(jnp.where(keep, d_Phi, 1.0), axis=1)   # (k,)
+    # shared product over the kept axes, (1, k); the first-moment factor
+    # rides on the target axis when the target is a kept axis
+    valid = j * k + jax.lax.broadcasted_iota(jnp.int32, (1, k), 1) < n
+    shared_cnt = jnp.where(valid, 1.0, 0.0)
+    shared_sm = shared_cnt
+    for a in range(d):
+        if a == g_axis:
+            continue
+        h = h_ref[a]                                   # SMEM scalars
+        xa = x[a:a + 1, :]
+        za = (lo_ref[a] - xa) * (1.0 / h)
+        zb = (hi_ref[a] - xa) * (1.0 / h)
+        d_Phi, d_phi = phi_terms(za, zb)
+        shared_cnt = shared_cnt * d_Phi
+        shared_sm = shared_sm * (xa * d_Phi - h * d_phi if a == tgt
+                                 else d_Phi)
 
-    valid = j * k + jax.lax.broadcasted_iota(jnp.int32, (k,), 0) < n
-    shared_cnt = jnp.where(valid, shared_cnt, 0.0)
-
-    xg = x[:, g_axis]
-    hg = h[g_axis]
-    gza = (glo[:, None] - xg[None, :]) / hg            # (gk, k)
-    gzb = (ghi[:, None] - xg[None, :]) / hg
-    g_Phi = 0.5 * (jax.scipy.special.erf(gzb * _SQRT1_2)
-                   - jax.scipy.special.erf(gza * _SQRT1_2))
-    cnt = g_Phi @ shared_cnt                           # (gk,) MXU matvec
-
-    if tgt_is_group:
-        g_dphi = _INV_SQRT_2PI * (jnp.exp(-0.5 * gzb * gzb)
-                                  - jnp.exp(-0.5 * gza * gza))
-        g_moment = xg[None, :] * g_Phi - hg * g_dphi
-        sm = g_moment @ shared_cnt
+    hg = h_ref[g_axis]
+    xg = x[g_axis:g_axis + 1, :]                       # (1, k)
+    gza = (glo - xg) * (1.0 / hg)                      # (gk, k)
+    gzb = (ghi - xg) * (1.0 / hg)
+    g_Phi, g_dphi = phi_terms(gza, gzb)
+    cnt = jnp.sum(g_Phi * shared_cnt, axis=1, keepdims=True)     # (gk, 1)
+    if tgt == g_axis:
+        g_moment = xg * g_Phi - hg * g_dphi
+        sm = jnp.sum(g_moment * shared_cnt, axis=1, keepdims=True)
     else:
-        d_phi = _INV_SQRT_2PI * (jnp.exp(-0.5 * zb * zb)
-                                 - jnp.exp(-0.5 * za * za))
-        moment = x * d_Phi - h[None, :] * d_phi
-        factors = jnp.where(axis == tgt, moment, d_Phi)
-        shared_sm = jnp.prod(jnp.where(keep, factors, 1.0), axis=1)
-        shared_sm = jnp.where(valid, shared_sm, 0.0)
-        sm = g_Phi @ shared_sm
+        sm = jnp.sum(g_Phi * shared_sm, axis=1, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += jnp.stack([cnt, sm], axis=1)       # (gk, 2)
+    out_ref[:, 0:1] += cnt
+    out_ref[:, 1:2] += sm
 
 
 @functools.partial(jax.jit, static_argnames=("g_axis", "tgt", "tile",
@@ -106,28 +95,27 @@ def _aqp_grouped_sums(x, h_diag, lo, hi, glo, ghi, g_axis, tgt, tile,
         z = jnp.zeros((G,), x.dtype)
         return z, z
 
-    k = min(tile, max(8, 1 << (n - 1).bit_length()))
-    gk = min(g_tile, max(8, 1 << (G - 1).bit_length()))
-    xp = jnp.pad(x, ((0, (-n) % k), (0, 0)))
-    glop = jnp.pad(glo, (0, (-G) % gk))
-    ghip = jnp.pad(ghi, (0, (-G) % gk))
+    # Layout: categories on sublanes, samples on lanes (x transposed).
+    k = lane_tile(tile, n)
+    gk = row_tile(g_tile, G)
+    xt = jnp.pad(x, ((0, (-n) % k), (0, 0))).T
+    glop = jnp.pad(glo, (0, (-G) % gk)).reshape(-1, 1)
+    ghip = jnp.pad(ghi, (0, (-G) % gk)).reshape(-1, 1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     out = pl.pallas_call(
-        functools.partial(_kernel, n=n, gk=gk, k=k, d=d, g_axis=g_axis,
-                          tgt=tgt, tgt_is_group=(tgt == g_axis)),
-        grid=(glop.shape[0] // gk, xp.shape[0] // k),
+        functools.partial(_kernel, n=n, k=k, d=d, g_axis=g_axis, tgt=tgt),
+        grid=(glop.shape[0] // gk, xt.shape[1] // k),
         in_specs=[
-            pl.BlockSpec((gk,), lambda i, j: (i,)),
-            pl.BlockSpec((gk,), lambda i, j: (i,)),
-            pl.BlockSpec((k, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
+            pl.BlockSpec((gk, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((gk, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((d, k), lambda i, j: (0, j)),
+            smem, smem, smem,
         ],
         out_specs=pl.BlockSpec((gk, 2), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((glop.shape[0], 2), x.dtype),
         interpret=interpret,
-    )(glop, ghip, xp, h_diag.astype(x.dtype), lo.astype(x.dtype),
+    )(glop, ghip, xt, h_diag.astype(x.dtype), lo.astype(x.dtype),
       hi.astype(x.dtype))
     return out[:G, 0], out[:G, 1]
 

@@ -44,7 +44,7 @@ import numpy as np
 
 from repro import knobs, obs
 
-from .tuning import env_int, profiled_call, resolve_tile
+from .tuning import interpret, profiled_call, resolve_tile, row_tile
 
 _SCHEMA_VERSION = 1
 
@@ -177,11 +177,6 @@ def load_cache(path: str) -> int:
 
 # --- sweeping ---------------------------------------------------------------
 
-def _interpret() -> bool:
-    import jax
-    return jax.default_backend() != "tpu"
-
-
 def _dedupe(cands: Sequence[Dict[str, int]]) -> List[Dict[str, int]]:
     seen, out = set(), []
     for c in cands:
@@ -195,7 +190,7 @@ def _dedupe(cands: Sequence[Dict[str, int]]) -> List[Dict[str, int]]:
 def _eff(tile: int, size: int) -> int:
     """The tile size the kernel will actually use after pow2 rounding —
     candidates that collapse to the same effective tiles are duplicates."""
-    return min(tile, max(8, 1 << (max(size, 1) - 1).bit_length()))
+    return row_tile(tile, size)
 
 
 def _grid(shape: Dict[str, int], axes: Dict[str, Tuple[str, Sequence[int]]],
@@ -254,7 +249,7 @@ def _make_aqp_batch(shape):
     a = jnp.asarray(rng.uniform(-4, 2, G).astype(np.float32))
     b = a + jnp.asarray(rng.uniform(0.2, 3, G).astype(np.float32))
     h = jnp.float32(0.5)
-    interp = _interpret()
+    interp = interpret()
     return lambda t: m.aqp_batch_sums(x, h, a, b, interpret=interp, **t)
 
 
@@ -268,7 +263,7 @@ def _make_aqp_boxes(shape):
     lo = jnp.asarray(rng.uniform(-3, 1, (G, d)).astype(np.float32))
     hi = lo + jnp.asarray(rng.uniform(0.2, 3, (G, d)).astype(np.float32))
     tgt = jnp.asarray(rng.integers(0, d, G), jnp.int32)
-    interp = _interpret()
+    interp = interpret()
     return lambda t: m.aqp_box_sums(x, h, lo, hi, tgt, interpret=interp, **t)
 
 
@@ -283,7 +278,7 @@ def _make_aqp_grouped(shape):
     hi = lo + 4.0
     glo = jnp.asarray(np.arange(G, dtype=np.float32) - 0.5)
     ghi = glo + 1.0
-    interp = _interpret()
+    interp = interpret()
     return lambda t: m.aqp_grouped_sums(x, h, lo, hi, glo, ghi, g_axis=0,
                                         tgt=min(1, d - 1),
                                         interpret=interp, **t)
@@ -302,7 +297,7 @@ def _make_qmc_reduce(shape):
     lo = jnp.asarray(rng.uniform(-3, 0, (G, d)).astype(np.float32))
     hi = lo + 2.0
     tgt = jnp.asarray(rng.integers(0, d, G), jnp.int32)
-    interp = _interpret()
+    interp = interpret()
     return lambda t: m.qmc_box_reduce(nodes, x, h_inv, log_norm, lo, hi,
                                       tgt, interpret=interp, **t)
 
@@ -321,11 +316,11 @@ def _make_rff(shape):
     w = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
     b = jnp.asarray(rng.uniform(0, 6.28, n).astype(np.float32))
     z = jnp.asarray(rng.normal(0, 1, n).astype(np.float32))
-    interp = _interpret()
+    interp = interpret()
     return lambda t: m.rff_density(pts, w, b, z, interpret=interp, **t)
 
 
-_POOL = (64, 128, 256, 512, 1024)
+_POOL = (128, 256, 512, 1024)     # lane axes: Mosaic blocks are 128-multiples
 _QPOOL = (16, 32, 64, 128, 256)
 
 SWEEPS: Dict[str, _Sweep] = {

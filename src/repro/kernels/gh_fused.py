@@ -19,11 +19,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .triangle import bx_to_ql, n_tri_tiles
-from .tuning import resolve_tile
+from .tuning import lane_tile, resolve_tile
 
 TILE = 256
+_LANES = 128
 
 
 def _kernel(e_ref, f_ref, m_ref, c_ref, out_ref, *, n: int, k: int):
@@ -32,14 +34,16 @@ def _kernel(e_ref, f_ref, m_ref, c_ref, out_ref, *, n: int, k: int):
     e = e_ref[...]                  # (k, d)
     f = f_ref[...]
     m = m_ref[...]                  # (d, d) = H^-1
-    c_k = c_ref[0]
+    c_k = c_ref[0]                  # SMEM scalars
     c_kk = c_ref[1]
 
-    me = e @ m
+    hp = jax.lax.Precision.HIGHEST
+    me = jnp.dot(e, m, precision=hp)
     qe = jnp.sum(me * e, axis=1)
-    mf = f @ m
+    mf = jnp.dot(f, m, precision=hp)
     qf = jnp.sum(mf * f, axis=1)
     cross = jax.lax.dot_general(me, f, (((1,), (1,)), ((), ())),
+                                precision=hp,
                                 preferred_element_type=jnp.float32)
     s = qe[:, None] + qf[None, :] - 2.0 * cross.astype(e.dtype)
 
@@ -47,7 +51,8 @@ def _kernel(e_ref, f_ref, m_ref, c_ref, out_ref, *, n: int, k: int):
     rows = q * k + jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
     cols = l * k + jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
     mask = (rows < cols) & (cols < n) & (rows < n)
-    out_ref[0] = jnp.sum(jnp.where(mask, t, 0.0))
+    out_ref[...] = jnp.full(out_ref.shape, jnp.sum(jnp.where(mask, t, 0.0)),
+                            out_ref.dtype)
 
 
 def gh_fused_sum(x: jax.Array, h_inv: jax.Array, c_k, c_kk,
@@ -63,13 +68,14 @@ def gh_fused_sum(x: jax.Array, h_inv: jax.Array, c_k, c_kk,
 def _gh_fused_sum(x: jax.Array, h_inv: jax.Array, c_k, c_kk,
                   tile: int, interpret: bool) -> jax.Array:
     n, d = x.shape
-    k = min(tile, max(8, 1 << (n - 1).bit_length())) if n < tile else tile
+    k = lane_tile(tile, n)
     pad = (-n) % k
     xp = jnp.pad(x, ((0, pad), (0, 0)))
     n_tiles = xp.shape[0] // k
     grid = (n_tri_tiles(n_tiles),)
     consts = jnp.stack([jnp.asarray(c_k, x.dtype), jnp.asarray(c_kk, x.dtype)])
 
+    # one (1, 1, 128) lane row per triangle tile holds its scalar partial
     partials = pl.pallas_call(
         functools.partial(_kernel, n=n, k=k),
         grid=grid,
@@ -77,10 +83,10 @@ def _gh_fused_sum(x: jax.Array, h_inv: jax.Array, c_k, c_kk,
             pl.BlockSpec((k, d), lambda bx: (bx_to_ql(bx)[0], 0)),
             pl.BlockSpec((k, d), lambda bx: (bx_to_ql(bx)[1], 0)),
             pl.BlockSpec((d, d), lambda bx: (0, 0)),
-            pl.BlockSpec((2,), lambda bx: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((1,), lambda bx: (bx,)),
-        out_shape=jax.ShapeDtypeStruct((grid[0],), x.dtype),
+        out_specs=pl.BlockSpec((1, 1, _LANES), lambda bx: (bx, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 1, _LANES), x.dtype),
         interpret=interpret,
     )(xp, xp, h_inv.astype(x.dtype), consts)
-    return jnp.sum(partials)
+    return jnp.sum(partials[:, 0, 0])

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: direct KDE evaluation (paper eq. 3) — the AQP serving
 hot spot (numerical integration of f^ evaluates the KDE at many grid points).
 
-Grid: (eval-tile, data-tile).  The (k,) output block for an eval tile stays
+Grid: (eval-tile, data-tile).  The (k, 1) output block for an eval tile stays
 resident while all data tiles stream through and accumulate
 
     f^(p) = norm * mean_i exp(-0.5 * ||p - x_i||^2 / h^2)
@@ -18,8 +18,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .tuning import resolve_tile
+from .tuning import lane_tile, resolve_tile
 
 TILE = 256
 
@@ -27,13 +28,13 @@ TILE = 256
 def _kernel(p_ref, x_ref, h_ref, out_ref, *, n: int, k: int, d: int):
     j = pl.program_id(1)
     p = p_ref[...]          # (k, d) eval points
-    x = x_ref[...]          # (k, d) data chunk
+    x = x_ref[...]          # (d, k) data chunk, transposed
     inv_h2 = 1.0 / (h_ref[0] * h_ref[0])
 
-    quad = jnp.zeros((k, k), p.dtype)
+    quad = None
     for a in range(d):
-        diff = p[:, a][:, None] - x[:, a][None, :]
-        quad = quad + diff * diff
+        diff = p[:, a:a + 1] - x[a:a + 1, :]
+        quad = diff * diff if quad is None else quad + diff * diff
     cols = j * k + jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
     vals = jnp.where(cols < n, jnp.exp(-0.5 * quad * inv_h2), 0.0)
 
@@ -41,7 +42,7 @@ def _kernel(p_ref, x_ref, h_ref, out_ref, *, n: int, k: int, d: int):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += jnp.sum(vals, axis=1)
+    out_ref[...] += jnp.sum(vals, axis=1, keepdims=True)
 
 
 def kde_eval(points: jax.Array, x: jax.Array, h: jax.Array,
@@ -63,24 +64,25 @@ def _kde_eval(points: jax.Array, x: jax.Array, h: jax.Array,
         x = x[:, None]
     m, d = points.shape
     n = x.shape[0]
-    k = min(tile, max(8, 1 << (max(m, n) - 1).bit_length()))
+    # eval points on sublanes, data on lanes; one k serves both axes
+    k = lane_tile(tile, max(m, n))
     pad_m = (-m) % k
     pad_n = (-n) % k
     pp = jnp.pad(points, ((0, pad_m), (0, 0)))
-    xp = jnp.pad(x, ((0, pad_n), (0, 0)))
+    xt = jnp.pad(x, ((0, pad_n), (0, 0))).T
 
     sums = pl.pallas_call(
         functools.partial(_kernel, n=n, k=k, d=d),
-        grid=(pp.shape[0] // k, xp.shape[0] // k),
+        grid=(pp.shape[0] // k, xt.shape[1] // k),
         in_specs=[
             pl.BlockSpec((k, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((k, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            pl.BlockSpec((d, k), lambda i, j: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((k,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((pp.shape[0],), x.dtype),
+        out_specs=pl.BlockSpec((k, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((pp.shape[0], 1), x.dtype),
         interpret=interpret,
-    )(pp, xp, h.reshape(1).astype(x.dtype))
+    )(pp, xt, h.reshape(1).astype(x.dtype))
 
     norm = (2.0 * math.pi) ** (-d / 2.0) * h ** (-d)
-    return (norm / n) * sums[:m]
+    return (norm / n) * sums[:m, 0]

@@ -19,31 +19,34 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .tuning import resolve_tile
+from .tuning import lane_tile, resolve_tile
 
 TILE = 256
 H_TILE = 8
+_LANES = 128
 
 
 def _kernel(s_ref, w_ref, hinv_ref, c_ref, out_ref, *, hk: int):
+    i = pl.program_id(0)   # h-tile index
     j = pl.program_id(1)   # S-tile index (minor: varies fastest)
     s = s_ref[...]         # (k, k) S values (masked entries are 0)
     w = w_ref[...]         # (k, k) mask weights in {0, 1}
-    c_k = c_ref[0]
+    c_k = c_ref[0]         # SMEM scalars
     c_kk = c_ref[1]
 
     @pl.when(j == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    acc = out_ref[...]
     for t in range(hk):            # unrolled over the h block
-        inv_h2 = hinv_ref[t]       # 1 / h^2
+        inv_h2 = hinv_ref[i * hk + t]       # 1 / h^2, SMEM
         e2 = jnp.exp(-0.5 * s * inv_h2)
         e4 = jnp.exp(-0.25 * s * inv_h2)
-        acc = acc.at[t].add(jnp.sum((c_kk * e4 - 2.0 * c_k * e2) * w))
-    out_ref[...] = acc
+        part = jnp.sum((c_kk * e4 - 2.0 * c_k * e2) * w)
+        out_ref[t:t + 1, :] += jnp.full((1, out_ref.shape[1]), part,
+                                        out_ref.dtype)
 
 
 def lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
@@ -70,7 +73,7 @@ def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
     n_h = h_grid.shape[0]
     s = _sv_matrix(x, sigma_inv, tile, "mxu", interpret)
 
-    k = min(tile, s.shape[0])
+    k = n if n <= tile else lane_tile(tile, n)
     pad = (-n) % k
     sp = jnp.pad(s, ((0, pad), (0, pad)))
     idx = jnp.arange(sp.shape[0])
@@ -78,7 +81,9 @@ def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
     w = w.astype(x.dtype)
     n_tiles = sp.shape[0] // k
 
-    hk = min(h_tile, n_h)
+    # per-h partials live in an (h, 128) lane-row block (scalar broadcast
+    # across lanes); the h block is the whole padded grid or a multiple of 8
+    hk = n_h if n_h <= h_tile else -(-h_tile // 8) * 8
     pad_h = (-n_h) % hk
     hinv = jnp.pad(1.0 / (h_grid * h_grid), (0, pad_h)).astype(x.dtype)
     n_h_tiles = hinv.shape[0] // hk
@@ -87,6 +92,7 @@ def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
     # Grid: (h-tile major, flattened S-tile minor) so the output block for a
     # given h-tile stays resident while all S tiles stream through.
     n_s_tiles = n_tiles * n_tiles
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     out = pl.pallas_call(
         functools.partial(_kernel, hk=hk),
@@ -94,11 +100,10 @@ def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
         in_specs=[
             pl.BlockSpec((k, k), lambda i, j: (j // n_tiles, j % n_tiles)),
             pl.BlockSpec((k, k), lambda i, j: (j // n_tiles, j % n_tiles)),
-            pl.BlockSpec((hk,), lambda i, j: (i,)),
-            pl.BlockSpec((2,), lambda i, j: (0,)),
+            smem, smem,
         ],
-        out_specs=pl.BlockSpec((hk,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((hinv.shape[0],), x.dtype),
+        out_specs=pl.BlockSpec((hk, _LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((hinv.shape[0], _LANES), x.dtype),
         interpret=interpret,
     )(sp, w, hinv, consts)
-    return out[:n_h]
+    return out[:n_h, 0]

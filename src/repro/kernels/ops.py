@@ -1,8 +1,9 @@
 """Public wrappers for the Pallas kernels.
 
-On this CPU container, kernels run in interpret mode (the kernel body executes
-in Python on CPU — correctness path); on a TPU runtime `interpret=False`
-compiles through Mosaic.  `INTERPRET` flips automatically on backend.
+Off the TPU (tests run with JAX_PLATFORMS=cpu) kernels run in interpret
+mode — the kernel body executes in Python on the CPU, the correctness path;
+on a TPU they compile through Mosaic.  `tuning.interpret()` is the one place
+that decides, per call, for these wrappers and the autotuner alike.
 
 Tile sizes resolve at CALL time, never at import: explicit kwarg > measured
 tile cache (`kernels/autotune.py`, keyed by kernel + bucketed shape) > env
@@ -13,13 +14,12 @@ a recompile.
 
 With `repro.obs` enabled, every wrapper routes through
 `tuning.profiled_call`, which records fenced wall/dispatch timings into the
-process-global metrics registry keyed by (kernel, shape, tile).  Disabled
-(the default), each wrapper takes the direct branch — same jitted callable,
-no fencing, no extra work.
+process-global metrics registry keyed by (kernel, shape, tile, interpret) —
+so a run can show which kernels compiled and which were interpreted.
+Disabled (the default), each wrapper takes the direct branch — same jitted
+callable, no fencing, no extra work.
 """
 from __future__ import annotations
-
-import jax
 
 from repro import obs
 
@@ -34,52 +34,51 @@ from . import pairwise_reduce as _pr
 from . import qmc_reduce as _qmc
 from . import rff_eval as _rff
 from . import sv_precompute as _sv
-from .tuning import profiled_call
+from .tuning import interpret, profiled_call
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _dispatch(kernel: str, run, **labels):
+    """Call `run(interpret)` directly, or profiled when `repro.obs` is on."""
+    interp = interpret()
+    if not obs.enabled():
+        return run(interp)
+    return profiled_call(kernel, lambda: run(interp), interpret=interp,
+                         **labels)
 
 
 def pairwise_scaled_ksum(x, g, kind="k4", tile=None):
     (tile,) = _tune.resolve(
         "pairwise_scaled_ksum", {"n": x.shape[0]},
         tile=(tile, "REPRO_PAIRWISE_TILE", _pr.TILE))
-    if not obs.enabled():
-        return _pr.pairwise_scaled_ksum(x, g, kind=kind, tile=tile,
-                                        interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "pairwise_scaled_ksum",
-        lambda: _pr.pairwise_scaled_ksum(x, g, kind=kind, tile=tile,
-                                         interpret=INTERPRET),
+        lambda interp: _pr.pairwise_scaled_ksum(x, g, kind=kind, tile=tile,
+                                                interpret=interp),
         n=x.shape[0], kind=kind, tile=tile)
 
 
 def sv_matrix(x, m, tile=None, algorithm="mxu"):
+    d = x.shape[1] if x.ndim > 1 else 1
     (tile,) = _tune.resolve(
-        "sv_matrix", {"n": x.shape[0], "d": x.shape[1] if x.ndim > 1 else 1},
+        "sv_matrix", {"n": x.shape[0], "d": d},
         tile=(tile, "REPRO_SV_TILE", _sv.TILE))
-    if not obs.enabled():
-        return _sv.sv_matrix(x, m, tile=tile, algorithm=algorithm,
-                             interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "sv_matrix",
-        lambda: _sv.sv_matrix(x, m, tile=tile, algorithm=algorithm,
-                              interpret=INTERPRET),
-        n=x.shape[0], d=x.shape[1] if x.ndim > 1 else 1, tile=tile,
-        algorithm=algorithm)
+        lambda interp: _sv.sv_matrix(x, m, tile=tile, algorithm=algorithm,
+                                     interpret=interp),
+        n=x.shape[0], d=d, tile=tile, algorithm=algorithm)
 
 
 def gh_fused_sum(x, h_inv, c_k, c_kk, tile=None):
+    d = x.shape[1] if x.ndim > 1 else 1
     (tile,) = _tune.resolve(
-        "gh_fused_sum", {"n": x.shape[0], "d": x.shape[1] if x.ndim > 1 else 1},
+        "gh_fused_sum", {"n": x.shape[0], "d": d},
         tile=(tile, "REPRO_GH_TILE", _gh.TILE))
-    if not obs.enabled():
-        return _gh.gh_fused_sum(x, h_inv, c_k, c_kk, tile=tile,
-                                interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "gh_fused_sum",
-        lambda: _gh.gh_fused_sum(x, h_inv, c_k, c_kk, tile=tile,
-                                 interpret=INTERPRET),
-        n=x.shape[0], d=x.shape[1] if x.ndim > 1 else 1, tile=tile)
+        lambda interp: _gh.gh_fused_sum(x, h_inv, c_k, c_kk, tile=tile,
+                                        interpret=interp),
+        n=x.shape[0], d=d, tile=tile)
 
 
 def lscv_grid_sums(x, sigma_inv, h_grid, c_k, c_kk, tile=None, h_tile=None):
@@ -87,13 +86,11 @@ def lscv_grid_sums(x, sigma_inv, h_grid, c_k, c_kk, tile=None, h_tile=None):
         "lscv_grid_sums", {"n": x.shape[0], "G": h_grid.shape[0]},
         tile=(tile, "REPRO_LSCV_TILE", _lg.TILE),
         h_tile=(h_tile, "REPRO_LSCV_H_TILE", _lg.H_TILE))
-    if not obs.enabled():
-        return _lg.lscv_grid_sums(x, sigma_inv, h_grid, c_k, c_kk, tile=tile,
-                                  h_tile=h_tile, interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "lscv_grid_sums",
-        lambda: _lg.lscv_grid_sums(x, sigma_inv, h_grid, c_k, c_kk, tile=tile,
-                                   h_tile=h_tile, interpret=INTERPRET),
+        lambda interp: _lg.lscv_grid_sums(x, sigma_inv, h_grid, c_k, c_kk,
+                                          tile=tile, h_tile=h_tile,
+                                          interpret=interp),
         n=x.shape[0], G=h_grid.shape[0], tile=tile, h_tile=h_tile)
 
 
@@ -101,27 +98,22 @@ def kde_eval(points, x, h, tile=None):
     (tile,) = _tune.resolve(
         "kde_eval", {"n": x.shape[0], "G": points.shape[0]},
         tile=(tile, "REPRO_KDE_EVAL_TILE", _kde.TILE))
-    if not obs.enabled():
-        return _kde.kde_eval(points, x, h, tile=tile, interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "kde_eval",
-        lambda: _kde.kde_eval(points, x, h, tile=tile, interpret=INTERPRET),
+        lambda interp: _kde.kde_eval(points, x, h, tile=tile,
+                                     interpret=interp),
         n=x.shape[0], G=points.shape[0], tile=tile)
 
 
 def aqp_batch_sums(x, h, a, b, tile=None, q_tile=None):
-    shape = {"n": x.shape[0], "G": a.shape[0]}
     tile, q_tile = _tune.resolve(
-        "aqp_batch_sums", shape,
+        "aqp_batch_sums", {"n": x.shape[0], "G": a.shape[0]},
         tile=(tile, "REPRO_AQP_TILE", _ab.TILE),
         q_tile=(q_tile, "REPRO_AQP_Q_TILE", _ab.Q_TILE))
-    if not obs.enabled():
-        return _ab.aqp_batch_sums(x, h, a, b, tile=tile, q_tile=q_tile,
-                                  interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "aqp_batch_sums",
-        lambda: _ab.aqp_batch_sums(x, h, a, b, tile=tile, q_tile=q_tile,
-                                   interpret=INTERPRET),
+        lambda interp: _ab.aqp_batch_sums(x, h, a, b, tile=tile,
+                                          q_tile=q_tile, interpret=interp),
         n=x.shape[0], G=a.shape[0], tile=tile, q_tile=q_tile)
 
 
@@ -131,30 +123,23 @@ def rff_density(points, w, b, z, tile=None, p_tile=None):
         "rff_density", shape,
         tile=(tile, "REPRO_RFF_TILE", _rff.TILE),
         p_tile=(p_tile, "REPRO_RFF_P_TILE", _rff.P_TILE))
-    if not obs.enabled():
-        return _rff.rff_density(points, w, b, z, tile=tile, p_tile=p_tile,
-                                interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "rff_density",
-        lambda: _rff.rff_density(points, w, b, z, tile=tile, p_tile=p_tile,
-                                 interpret=INTERPRET),
+        lambda interp: _rff.rff_density(points, w, b, z, tile=tile,
+                                        p_tile=p_tile, interpret=interp),
         n=points.shape[0], D=w.shape[0], tile=tile, p_tile=p_tile)
 
 
 def aqp_box_sums(x, h_diag, lo, hi, tgt, tile=None, q_tile=None):
     d = x.shape[1] if x.ndim > 1 else 1
-    shape = {"n": x.shape[0], "d": d, "G": lo.shape[0]}
     tile, q_tile = _tune.resolve(
-        "aqp_box_sums", shape,
+        "aqp_box_sums", {"n": x.shape[0], "d": d, "G": lo.shape[0]},
         tile=(tile, "REPRO_AQP_BOXES_TILE", _abx.TILE),
         q_tile=(q_tile, "REPRO_AQP_BOXES_Q_TILE", _abx.Q_TILE))
-    if not obs.enabled():
-        return _abx.aqp_box_sums(x, h_diag, lo, hi, tgt, tile=tile,
-                                 q_tile=q_tile, interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "aqp_box_sums",
-        lambda: _abx.aqp_box_sums(x, h_diag, lo, hi, tgt, tile=tile,
-                                  q_tile=q_tile, interpret=INTERPRET),
+        lambda interp: _abx.aqp_box_sums(x, h_diag, lo, hi, tgt, tile=tile,
+                                         q_tile=q_tile, interpret=interp),
         n=x.shape[0], d=d, G=lo.shape[0], tile=tile, q_tile=q_tile)
 
 
@@ -165,15 +150,11 @@ def aqp_grouped_sums(x, h_diag, lo, hi, glo, ghi, g_axis, tgt,
         "aqp_grouped_sums", shape,
         tile=(tile, "REPRO_AQP_GROUPED_TILE", _agr.TILE),
         g_tile=(g_tile, "REPRO_AQP_GROUPED_G_TILE", _agr.G_TILE))
-    if not obs.enabled():
-        return _agr.aqp_grouped_sums(x, h_diag, lo, hi, glo, ghi, g_axis,
-                                     tgt, tile=tile, g_tile=g_tile,
-                                     interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "aqp_grouped_sums",
-        lambda: _agr.aqp_grouped_sums(x, h_diag, lo, hi, glo, ghi, g_axis,
-                                      tgt, tile=tile, g_tile=g_tile,
-                                      interpret=INTERPRET),
+        lambda interp: _agr.aqp_grouped_sums(
+            x, h_diag, lo, hi, glo, ghi, g_axis, tgt, tile=tile,
+            g_tile=g_tile, interpret=interp),
         n=x.shape[0], d=x.shape[1], G=glo.shape[0], tile=tile, g_tile=g_tile)
 
 
@@ -186,14 +167,10 @@ def qmc_box_reduce(nodes, x, h_inv, log_norm, lo, hi, tgt,
         tile=(tile, "REPRO_QMC_TILE", _qmc.TILE),
         m_tile=(m_tile, "REPRO_QMC_M_TILE", _qmc.M_TILE),
         q_tile=(q_tile, "REPRO_QMC_Q_TILE", _qmc.Q_TILE))
-    if not obs.enabled():
-        return _qmc.qmc_box_reduce(nodes, x, h_inv, log_norm, lo, hi, tgt,
-                                   tile=tile, m_tile=m_tile, q_tile=q_tile,
-                                   interpret=INTERPRET)
-    return profiled_call(
+    return _dispatch(
         "qmc_box_reduce",
-        lambda: _qmc.qmc_box_reduce(nodes, x, h_inv, log_norm, lo, hi, tgt,
-                                    tile=tile, m_tile=m_tile, q_tile=q_tile,
-                                    interpret=INTERPRET),
+        lambda interp: _qmc.qmc_box_reduce(
+            nodes, x, h_inv, log_norm, lo, hi, tgt, tile=tile, m_tile=m_tile,
+            q_tile=q_tile, interpret=interp),
         n=x.shape[0], d=x.shape[1], G=lo.shape[0], m=nodes.shape[0],
         tile=tile, m_tile=m_tile, q_tile=q_tile)

@@ -7,7 +7,7 @@ One launch evaluates the RFF synopsis dot product for a batch of points:
 with the fitted state (W, b, z) from `repro.synopses.rff` (z carries the
 2/D feature scale and the sample mean; the caller applies the kernel
 normaliser and the zero clip).  Grid: (point-tile major, feature-tile
-minor) — the (pk,) accumulator block stays resident while feature tiles
+minor) — the (pk, 1) accumulator block stays resident while feature tiles
 stream through, the same pattern as aqp_boxes.py.  Padded features
 contribute exactly zero because z is zero-padded, so no feature mask is
 needed; padded points are sliced off by the caller.
@@ -24,21 +24,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .tuning import resolve_tile
+from .tuning import lane_tile, resolve_tile, row_tile
 
 TILE = 512     # feature-tile default (env: REPRO_RFF_TILE)
 P_TILE = 256   # point-tile default (env: REPRO_RFF_P_TILE)
 
 
-def _kernel(p_ref, w_ref, b_ref, z_ref, out_ref):
+def _kernel(p_ref, w_ref, b_ref, z_ref, out_ref, *, d: int):
     j = pl.program_id(1)     # feature-tile index (minor: varies fastest)
     p = p_ref[...]           # (pk, d) query points (padded rows harmless)
-    w = w_ref[...]           # (fk, d) feature frequencies
-    b = b_ref[...]           # (fk,)  feature phases
-    z = z_ref[...]           # (fk,)  scaled sample feature mean (0 on pad)
+    w = w_ref[...]           # (d, fk) feature frequencies, transposed
+    b = b_ref[...]           # (1, fk) feature phases
+    z = z_ref[...]           # (fk, 1) scaled sample feature mean (0 on pad)
 
-    proj = jnp.dot(p, w.T) + b[None, :]          # (pk, fk)
-    partial = jnp.cos(proj) @ z                  # (pk,)
+    # the projection is d broadcast multiply-adds on the VPU (d is small),
+    # exact f32; the feature contraction runs on the MXU at f32 precision
+    proj = b
+    for a in range(d):
+        proj = proj + p[:, a:a + 1] * w[a:a + 1, :]    # (pk, fk)
+    partial = jnp.dot(jnp.cos(proj), z,
+                      precision=jax.lax.Precision.HIGHEST)   # (pk, 1)
 
     @pl.when(j == 0)
     def _init():
@@ -54,28 +59,30 @@ def _rff_density(points, w, b, z, tile, p_tile, interpret):
     if m == 0 or D == 0:
         return jnp.zeros((m,), points.dtype)
 
-    pk = min(p_tile, max(8, 1 << (m - 1).bit_length()))
-    fk = min(tile, max(8, 1 << (D - 1).bit_length()))
+    # Layout: points on sublanes, features on lanes; no operand is 1-D, so
+    # no block depends on XLA's tiling of a vector.
+    pk = row_tile(p_tile, m)
+    fk = lane_tile(tile, D)
+    dt = points.dtype
     pp = jnp.pad(points, ((0, (-m) % pk), (0, 0)))
-    wp = jnp.pad(w, ((0, (-D) % fk), (0, 0)))
-    bp = jnp.pad(b, (0, (-D) % fk))
-    zp = jnp.pad(z, (0, (-D) % fk))
+    wt = jnp.pad(w, ((0, (-D) % fk), (0, 0))).astype(dt).T
+    bp = jnp.pad(b, (0, (-D) % fk)).astype(dt).reshape(1, -1)
+    zp = jnp.pad(z, (0, (-D) % fk)).astype(dt).reshape(-1, 1)
 
     out = pl.pallas_call(
-        _kernel,
-        grid=(pp.shape[0] // pk, wp.shape[0] // fk),
+        functools.partial(_kernel, d=d),
+        grid=(pp.shape[0] // pk, wt.shape[1] // fk),
         in_specs=[
             pl.BlockSpec((pk, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((fk, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((fk,), lambda i, j: (j,)),
-            pl.BlockSpec((fk,), lambda i, j: (j,)),
+            pl.BlockSpec((d, fk), lambda i, j: (0, j)),
+            pl.BlockSpec((1, fk), lambda i, j: (0, j)),
+            pl.BlockSpec((fk, 1), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((pk,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((pp.shape[0],), points.dtype),
+        out_specs=pl.BlockSpec((pk, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((pp.shape[0], 1), dt),
         interpret=interpret,
-    )(pp, wp.astype(points.dtype), bp.astype(points.dtype),
-      zp.astype(points.dtype))
-    return out[:m]
+    )(pp, wt, bp, zp)
+    return out[:m, 0]
 
 
 def rff_density(points: jax.Array, w: jax.Array, b: jax.Array, z: jax.Array,

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .tuning import resolve_tile
+from .tuning import lane_tile, resolve_tile
 
 TILE = 256
 
@@ -53,11 +53,13 @@ def _kernel(e_ref, f_ref, m_ref, out_ref, *, n: int, k: int, d: int, algorithm: 
             y = y + part * (e[:, a][:, None] - f[:, a][None, :])
     else:
         # "mxu": S = qe[:,None] + qf[None,:] - 2 E M F^T   (M symmetric)
-        me = e @ m                                   # (k, d) MXU
+        hp = jax.lax.Precision.HIGHEST               # f32 passes on the MXU
+        me = jnp.dot(e, m, precision=hp)             # (k, d) MXU
         qe = jnp.sum(me * e, axis=1)                 # (k,)
-        mf = f @ m
+        mf = jnp.dot(f, m, precision=hp)
         qf = jnp.sum(mf * f, axis=1)                 # (k,)
         cross = jax.lax.dot_general(me, f, (((1,), (1,)), ((), ())),
+                                    precision=hp,
                                     preferred_element_type=jnp.float32)  # (k, k)
         y = qe[:, None] + qf[None, :] - 2.0 * cross.astype(e.dtype)
 
@@ -80,7 +82,7 @@ def sv_matrix(x: jax.Array, m: jax.Array, tile=None,
 def _sv_matrix(x: jax.Array, m: jax.Array, tile: int,
                algorithm: str, interpret: bool) -> jax.Array:
     n, d = x.shape
-    k = min(tile, max(8, 1 << (n - 1).bit_length())) if n < tile else tile
+    k = lane_tile(tile, n)
     pad = (-n) % k
     xp = jnp.pad(x, ((0, pad), (0, 0)))
     n_tiles = xp.shape[0] // k

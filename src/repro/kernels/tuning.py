@@ -24,6 +24,8 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Tuple
 
+import jax
+
 from repro import knobs, obs
 
 
@@ -48,6 +50,31 @@ def resolve_tile(env_name: str, default: int, override=None) -> int:
                              f"got {override!r}")
         return value
     return env_int(env_name, default)
+
+
+def interpret() -> bool:
+    """Whether the Pallas kernels run in the interpreter: exactly when JAX's
+    default backend is not a TPU.  The one decision point for every kernel
+    dispatch (ops.py wrappers and autotune sweeps); a serving process that
+    must run compiled kernels checks the platform itself, as chip_smoke.py
+    does, rather than trusting this fallback."""
+    return jax.default_backend() != "tpu"
+
+
+def row_tile(tile: int, n: int) -> int:
+    """Block extent along a sublane (second-to-last) axis of size n: the
+    whole axis, padded to a power of two (at least 8), when it fits in one
+    tile; otherwise `tile` rounded up to a multiple of 8."""
+    full = max(8, 1 << (max(n, 1) - 1).bit_length())
+    return full if full <= tile else -(-tile // 8) * 8
+
+
+def lane_tile(tile: int, n: int) -> int:
+    """Block extent along a lane (last) axis of size n: the whole padded
+    axis when it fits in one tile; otherwise `tile` rounded up to a multiple
+    of 128, the lane width Mosaic requires of a partial block."""
+    full = max(8, 1 << (max(n, 1) - 1).bit_length())
+    return full if full <= tile else -(-tile // 128) * 128
 
 
 def profiled_call(kernel: str, fn, /, *args, **labels):

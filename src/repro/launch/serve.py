@@ -166,6 +166,9 @@ def make_mixed_aqp_queries(n_queries: int, ranges, joint_cols, cat_col,
     return queries
 
 
+_JOINT_COLS = ("loss", "latency_ms")
+
+
 def _make_telemetry(rng, n):
     import numpy as np
 
@@ -208,24 +211,17 @@ def _print_metrics_summary(store) -> None:
           f"{store.metrics.sum_counter('aqp.ingest.batches')} batches")
 
 
-def run_aqp(args) -> None:
-    import threading
-    from collections import Counter
-
+def seed_aqp_store(args):
+    """The store `--mode aqp` serves: warm-started from the latest snapshot
+    under --snapshot-dir (--restore), or seeded with --rows synthetic
+    telemetry rows.  Returns (store, rows_seen, restored_step, telemetry);
+    telemetry is the seeded column dict, None after a restore."""
     import numpy as np
 
-    from repro import obs
-    from repro.core import AqpQuery, Range
     from repro.data import TelemetryStore
 
-    if args.metrics_out or args.trace_out:
-        # spans + fenced latency histograms + kernel profiling for this run
-        obs.enable()
-
-    rng = np.random.default_rng(0)
-    n = args.rows
-    joint_cols = ("loss", "latency_ms")
     restored_step = None
+    telemetry = None
     if args.restore:
         if not args.snapshot_dir:
             raise SystemExit("--restore needs --snapshot-dir")
@@ -240,17 +236,40 @@ def run_aqp(args) -> None:
         store = TelemetryStore.load(args.snapshot_dir)
         n = max(res.n_seen for res in store.columns.values())
     else:
-        telemetry = _make_telemetry(rng, n)
+        n = args.rows
+        telemetry = _make_telemetry(np.random.default_rng(0), n)
         store = TelemetryStore(capacity=args.capacity, seed=0)
         # tiered ladders (before add_batch, like joints): tier 0 serves the
         # "coarse" priority class, the top tier IS the full sample
         store.track_tiered("loss", n_tiers=4)
         store.track_tiered("latency_ms", n_tiers=4)
-        store.track_tiered(joint_cols, n_tiers=4)   # joints sample whole rows
+        store.track_tiered(_JOINT_COLS, n_tiers=4)  # joints sample whole rows
         store.track_categorical("model_id")  # exact per-code counts for Eq terms
         store.add_batch(telemetry)
         # registering after add_batch backfills from the per-column reservoirs
         store.track_joint(("model_id", "latency_ms"))
+    return store, n, restored_step, telemetry
+
+
+def run_aqp(args, seeded=None) -> dict:
+    """Serve one `--mode aqp` run and print its summary.  `seeded` is a
+    `seed_aqp_store(args)` result to serve from (default: seed a new one).
+    Returns the answers: {"results": the clients' AqpResults in client
+    order, "group_by": the GROUP BY results}."""
+    import threading
+    from collections import Counter
+
+    import numpy as np
+
+    from repro import obs
+    from repro.core import AqpQuery, Range
+
+    if args.metrics_out or args.trace_out:
+        # spans + fenced latency histograms + kernel profiling for this run
+        obs.enable()
+
+    store, n, restored_step, _ = seeded or seed_aqp_store(args)
+    joint_cols = _JOINT_COLS
     # query-mix sampling ranges come from the reservoir samples (not the raw
     # stream) on BOTH paths, so a restarted process regenerates the exact
     # same client query stream as the run that wrote the snapshot — with a
@@ -286,6 +305,7 @@ def run_aqp(args) -> None:
                              max_pending=args.max_pending,
                              overflow=args.overflow)
     per_client: dict = {}
+    client_errors: list = []
     results_lock = threading.Lock()
     stop_producer = threading.Event()
     snapshots = [0]
@@ -320,10 +340,15 @@ def run_aqp(args) -> None:
             fullh_frac=args.fullh_frac)
         crng = np.random.default_rng(500 + ci)
         got = []
-        for q in specs:                       # closed loop: 1 outstanding
-            priority = "coarse" if crng.random() < args.coarse_frac \
-                else None                     # None -> the session default
-            got.append(session.submit(q, priority=priority).result())
+        try:
+            for q in specs:                   # closed loop: 1 outstanding
+                priority = "coarse" if crng.random() < args.coarse_frac \
+                    else None                 # None -> the session default
+                got.append(session.submit(q, priority=priority).result())
+        except BaseException as exc:          # re-raised after the join
+            with results_lock:
+                client_errors.append(exc)
+            return
         with results_lock:
             per_client[ci] = got
 
@@ -363,6 +388,9 @@ def run_aqp(args) -> None:
         export_metrics()    # final snapshot includes the closing flush
     if args.trace_out:
         obs.get_tracer().export_jsonl(args.trace_out)
+    if client_errors:
+        # a failed flush reaches its clients through their futures
+        raise client_errors[0]
 
     # client order (not thread finish order): the sample rows below are
     # reproducible run-to-run when the producer is quiescent
@@ -439,9 +467,11 @@ def run_aqp(args) -> None:
     print(f"[serve:aqp] AVG(latency_ms) GROUP BY model_id "
           f"[{gb[0].path}]: "
           + ", ".join(f"{r.group:.0f}: {r.estimate:.1f}" for r in gb))
+    return {"results": results, "group_by": gb}
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
+    """The serve CLI's arguments (`argv=None` reads sys.argv), validated."""
     from repro.configs.base import ARCH_IDS
 
     ap = argparse.ArgumentParser()
@@ -510,7 +540,7 @@ def main() -> None:
     ap.add_argument("--trace-out", default=None,
                     help="append the span ring as JSON lines on exit "
                          "(enables repro.obs)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.snapshot_every < 1:
         ap.error(f"--snapshot-every must be >= 1, got {args.snapshot_every}")
     if args.metrics_every <= 0:
@@ -519,7 +549,13 @@ def main() -> None:
         ap.error(f"--coarse-frac must be in [0, 1], got {args.coarse_frac}")
     if not 0.0 <= args.fullh_frac <= 1.0:
         ap.error(f"--fullh-frac must be in [0, 1], got {args.fullh_frac}")
+    return args
 
+
+def main() -> None:
+    args = parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.mode == "aqp":
         run_aqp(args)
     else:

@@ -70,6 +70,10 @@ from .base import DensitySynopsis, register
 # O(chunk * n_features) even for 200k+ row reservoirs
 FIT_CHUNK = 4096
 
+# f32 matmul passes: XLA on a TPU defaults f32 dots to one bf16 pass, which
+# would put ~1e-2 rad of phase error on |w.x| ~ 10^2 projections
+_F32 = jax.lax.Precision.HIGHEST
+
 
 @partial(jax.jit, static_argnames=("chunk",))
 def _mean_cos(x: jax.Array, w: jax.Array, b: jax.Array,
@@ -83,7 +87,7 @@ def _mean_cos(x: jax.Array, w: jax.Array, b: jax.Array,
 
     def body(acc, args):
         xc, mc = args
-        proj = xc @ w.T + b[None, :]                      # (c, D)
+        proj = jnp.dot(xc, w.T, precision=_F32) + b[None, :]   # (c, D)
         return acc + jnp.sum(jnp.cos(proj) * mc[:, None], axis=0), None
 
     acc, _ = jax.lax.scan(
@@ -108,7 +112,8 @@ def _block_densities(points: jax.Array, w: jax.Array, b: jax.Array,
 
     def one(args):
         wk, bk, zk = args
-        raw = jnp.cos(points @ wk.T + bk[None, :]) @ zk
+        proj = jnp.dot(points, wk.T, precision=_F32) + bk[None, :]
+        raw = jnp.dot(jnp.cos(proj), zk, precision=_F32)
         return norm * rescale * raw
 
     # lax.map, not vmap: vmap would materialise the full (B, m, D/B) cos

@@ -155,7 +155,35 @@ def test_batched_engine_matches_query_loop(rng, backend):
     got = QueryBatch(queries).run(syn, backend=backend)
     fns = {"count": syn.count, "sum": syn.sum, "avg": syn.avg}
     want = np.asarray([float(fns[q.op](q.a, q.b)) for q in queries])
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    if backend == "jnp":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+        return
+    # The Pallas kernel evaluates erf with its own f32 approximation
+    # (kernels/erf.py), the reference with jax's; each is within
+    # ERF_ABS_ERR of the true erf.  Per sample, the Phi difference of the
+    # two paths then differs by at most 2 * ERF_ABS_ERR (two erf terms,
+    # each halved, on each side) plus the f32 rounding of Phi near 1 on
+    # the reference side (2^-23).  Summed over the sample and scaled to the
+    # relation, COUNT may differ by n_source times that, SUM by the same
+    # times max|x|, and AVG = SUM / COUNT by the propagated ratio error.
+    from repro.kernels.erf import ERF_ABS_ERR
+
+    cnt_tol = syn.n_source * (2 * ERF_ABS_ERR + 2.0 ** -23)
+    sum_tol = cnt_tol * float(np.max(np.abs(np.asarray(syn.x))))
+    tol = []
+    for q, w in zip(queries, want):
+        if q.op == "count":
+            tol.append(cnt_tol)
+        elif q.op == "sum":
+            tol.append(sum_tol)
+        else:
+            cnt = float(syn.count(q.a, q.b))
+            tol.append((sum_tol + abs(w) * cnt_tol) / max(cnt, 1e-3))
+    # plus f32 summation order (the kernel sums in tiles)
+    bound = np.asarray(tol) + 1e-5 * np.abs(want)
+    bad = np.abs(got - want) > bound
+    assert not bad.any(), (np.flatnonzero(bad), got[bad], want[bad],
+                           bound[bad])
 
 
 def test_query_batch_groups_columns(rng):

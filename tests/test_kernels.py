@@ -269,3 +269,17 @@ def test_aqp_batch_sums_empty_sample():
     c, s = ops.aqp_batch_sums(x, jnp.float32(0.5), a, b)
     np.testing.assert_array_equal(np.asarray(c), 0.0)
     np.testing.assert_array_equal(np.asarray(s), 0.0)
+
+
+def test_kernel_erf_within_stated_bound():
+    """The in-kernel f32 erf stays within ERF_ABS_ERR of the float64 erf
+    over the whole f32 range the closed forms feed it."""
+    import math
+
+    from repro.kernels.erf import ERF_ABS_ERR, erf
+
+    x = np.linspace(-9.0, 9.0, 400_001).astype(np.float32)
+    got = np.asarray(erf(jnp.asarray(x)), np.float64)
+    want = np.asarray([math.erf(float(v)) for v in x])
+    assert np.max(np.abs(got - want)) <= ERF_ABS_ERR
+    assert got[0] == -1.0 and got[-1] == 1.0

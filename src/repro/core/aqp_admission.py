@@ -348,16 +348,22 @@ class AqpSession:
             raise ValueError(f"unknown priority {name!r}; "
                              f"have {sorted(self.priority_tiers)}")
         tier = self.priority_tiers[name]
-        # The submit span is the root of the query's trace; its ctx rides on
-        # every _Pending so the flush (another thread) can parent onto it.
-        with obs.span("admission.submit", aggregate=query.aggregate,
-                      priority=name, session=self.sid) as sp:
-            parts = self.engine.compile(query)
+        # The submit span is the root of the query's trace, even when a
+        # done-callback submits from inside another query's flush; its ctx
+        # rides on every _Pending so the flush (another thread) can parent
+        # onto it.
+        with obs.span("admission.submit", root=True,
+                      aggregate=query.aggregate, priority=name,
+                      session=self.sid) as sp:
+            with obs.span("engine.compile"):
+                parts = self.engine.compile(query)
+            sp.set(parts=len(parts))
             resolver = self.engine.resolver(self.selector, tier=tier)
             keyed = []
-            for c in parts:
-                key3, c2, version = resolver.key_for(c)
-                keyed.append((key3 + (version,), c2))
+            with obs.span("engine.key", parts=len(parts)):
+                for c in parts:
+                    key3, c2, version = resolver.key_for(c)
+                    keyed.append((key3 + (version,), c2))
             ticket = _Ticket(len(parts), single=query.group_by is None)
             due: List[BucketKey] = []
             with self._lock:
@@ -379,14 +385,16 @@ class AqpSession:
                         and self._thread is None:
                     self._start_flusher()
                 self._wakeup.notify_all()
-        # Past-deadline buckets flush first (oldest-first, via poll): without
-        # this, a lone sub-watermark ticket whose deadline has passed would
-        # keep waiting for the background flusher even while fresh submits
-        # prove the session is alive.
-        if self.max_delay is not None:
-            self.poll()
-        for key in due:
-            self._flush_key(key, FLUSH_WATERMARK)
+            # Past-deadline buckets flush first (oldest-first, via poll):
+            # without this, a lone sub-watermark ticket whose deadline has
+            # passed would keep waiting for the background flusher even while
+            # fresh submits prove the session is alive.
+            if self.max_delay is not None or due:
+                with obs.span("admission.inline_flush"):
+                    if self.max_delay is not None:
+                        self.poll()
+                    for key in due:
+                        self._flush_key(key, FLUSH_WATERMARK)
         return ticket.future
 
     def submit_many(self, queries: Sequence[AqpQuery],
@@ -701,45 +709,44 @@ class AqpSession:
         # Parent the flush span onto the oldest pending's submit span: the
         # trace started at submit() continues here even though the flush runs
         # on a different thread (the ctx tuple made the hop explicitly).
-        t0 = time.perf_counter()
+        now = self.time_fn()
+        wait_us = sum(now - p.submitted_at for p in pendings) * 1e6
         with obs.span("admission.flush", parent=pendings[0].ctx,
-                      reason=reason, batch=len(pendings), key=key[0],
-                      tier=key[2], session=self.sid):
+                      reason=reason, batch=len(pendings), wait_us=wait_us,
+                      key=key[0], tier=key[2], session=self.sid):
             try:
                 results = self.engine.run_compiled(
                     compiled, selector=self.selector, backend=self.backend,
                     tier=key[2])
             except BaseException as exc:        # surface through the futures
                 error = exc
-        if obs.enabled():
-            self.metrics.histogram("aqp.admission.flush_us",
-                                   session=self.sid).observe(
-                (time.perf_counter() - t0) * 1e6)
-        done: List[_Ticket] = []
-        with self._lock:
-            self._c_flushes.inc()
-            self.metrics.counter("aqp.admission.flush_reason",
-                                 session=self.sid, reason=reason).inc()
-            self._c_batch_rows.inc(len(pendings))
-            self._h_batch.observe(len(pendings))
-            self._c_executed.inc(len(pendings))
-            if len(pendings) > 1:
-                self._c_coalesced.inc(len(pendings))
-            for p in pendings:
-                t = p.ticket
-                if error is not None:
-                    t.failed = True
-                else:
-                    t.parts[p.part] = results[p.compiled.slot]
-                t.remaining -= 1
-                if t.remaining == 0:
-                    done.append(t)
-        # futures resolve outside the lock: done-callbacks may re-enter the
-        # session (e.g. a client submitting its next query inline)
-        for t in done:
-            if t.failed:
-                t.future.set_exception(
-                    error if error is not None
-                    else RuntimeError("admission flush failed"))
-            else:
-                t.future.set_result(t.parts[0] if t.single else list(t.parts))
+            done: List[_Ticket] = []
+            with self._lock:
+                self._c_flushes.inc()
+                self.metrics.counter("aqp.admission.flush_reason",
+                                     session=self.sid, reason=reason).inc()
+                self._c_batch_rows.inc(len(pendings))
+                self._h_batch.observe(len(pendings))
+                self._c_executed.inc(len(pendings))
+                if len(pendings) > 1:
+                    self._c_coalesced.inc(len(pendings))
+                for p in pendings:
+                    t = p.ticket
+                    if error is not None:
+                        t.failed = True
+                    else:
+                        t.parts[p.part] = results[p.compiled.slot]
+                    t.remaining -= 1
+                    if t.remaining == 0:
+                        done.append(t)
+            # futures resolve outside the lock: done-callbacks may re-enter the
+            # session (e.g. a client submitting its next query inline)
+            with obs.span("admission.resolve", n=len(done)):
+                for t in done:
+                    if t.failed:
+                        t.future.set_exception(
+                            error if error is not None
+                            else RuntimeError("admission flush failed"))
+                    else:
+                        t.future.set_result(t.parts[0] if t.single
+                                            else list(t.parts))

@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from .aqp import AVG_MIN_COUNT, OP_COUNT, OP_SUM, _Phi, _phi
 
 DEFAULT_CI_LEVEL = 0.95
@@ -174,7 +176,10 @@ def se_from_moments(ops: np.ndarray, moments, scale: float,
     (scaled count below AVG_MIN_COUNT, where the engine pins AVG to 0) get an
     infinite SE — the estimate is a guard value, not an estimator.
     """
-    m1c, m1s, m2c, m2s, m12 = (np.asarray(v, np.float64) for v in moments)
+    # the host waits here for the moments pass: one copy per moment sum
+    with obs.span("engine.fetch", n=len(ops)):
+        m1c, m1s, m2c, m2s, m12 = (np.asarray(v, np.float64)
+                                   for v in moments)
     ops = np.asarray(ops)
     if m < 2:
         return np.full(m1c.shape, np.inf)

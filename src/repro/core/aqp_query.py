@@ -827,7 +827,10 @@ def _run_group(key, plan: _GroupPlan, entries: List[_Compiled],
     # immediately, and the kernel invocations themselves are untouched — so
     # disabled-mode execution stays bit-identical with no extra jit traces
     # (both test-enforced).  Fencing inside the kernel/CI spans makes their
-    # durations device-true instead of async-dispatch artifacts.
+    # durations device-true instead of async-dispatch artifacts.  In
+    # profiler mode nothing fences: kernel and CI spans time host
+    # preparation and dispatch, and `engine.fetch` the host's wait for the
+    # device.
     enabled = obs.enabled()
 
     # Full-H entries whose resolved density backend is the fitted sublinear
@@ -899,8 +902,9 @@ def _run_group(key, plan: _GroupPlan, entries: List[_Compiled],
                 se = se_from_moments(ops_np, mom, plan.scale, n_eff)
                 obs.fence(se)
             q_ci = norm_ppf(p)
-        ans_np = np.asarray(ans, np.float64)[:n]
-        se_np = np.asarray(se, np.float64)[:n]
+        with obs.span("engine.fetch", n=n):
+            ans_np = np.asarray(ans, np.float64)[:n]
+            se_np = np.asarray(se, np.float64)[:n]
         if enabled and metrics is not None:
             metrics.histogram("aqp.query.latency_us", path=path,
                               tier=tier).observe(
@@ -933,8 +937,9 @@ def _run_group(key, plan: _GroupPlan, entries: List[_Compiled],
                                  syn.n_source, n_qmc)
             obs.fence(se)
         q_ci = t_ppf(p, dof)
-        ans_np = np.asarray(ans, np.float64)[:n]
-        se_np = np.asarray(se, np.float64)[:n]
+        with obs.span("engine.fetch", n=n):
+            ans_np = np.asarray(ans, np.float64)[:n]
+            se_np = np.asarray(se, np.float64)[:n]
         if enabled and metrics is not None:
             lat = (time.perf_counter() - t_grp) * 1e6
             metrics.histogram("aqp.query.latency_us", path="qmc:rff",
@@ -963,7 +968,8 @@ def _run_group(key, plan: _GroupPlan, entries: List[_Compiled],
                 g_axis=g_axis, tgt=fam[0].tgt, op=fam[0].op, scale=scale,
                 backend=backend)
             obs.fence(ans)
-        ans_np = np.asarray(ans, np.float64)[:len(fam)]
+        with obs.span("engine.fetch", n=len(fam)):
+            ans_np = np.asarray(ans, np.float64)[:len(fam)]
         # family moments run on the per-entry FULL boxes (each entry's box
         # already carries its group window from _compile)
         flo = _pad_rows(np.asarray([c.lo for c in fam], np.float32), gm)
@@ -975,7 +981,8 @@ def _run_group(key, plan: _GroupPlan, entries: List[_Compiled],
                               jnp.asarray(fhi), jnp.asarray(ftgt))
             se = se_from_moments(fops, mom, plan.scale, n_eff)
             obs.fence(se)
-        se_np = np.asarray(se, np.float64)[:len(fam)]
+        with obs.span("engine.fetch", n=len(fam)):
+            se_np = np.asarray(se, np.float64)[:len(fam)]
         if enabled and metrics is not None:
             metrics.histogram("aqp.query.latency_us", path=fam_path,
                               tier=tier).observe(
@@ -1000,20 +1007,21 @@ def _execute(compiled: Sequence[_Compiled], n_out: int, resolver,
     results: List[Optional[AqpResult]] = [None] * n_out
     try_exact = getattr(resolver, "try_exact", None)
     remaining: List[_Compiled] = []
-    for c in compiled:
-        hit = try_exact(c) if try_exact is not None else None
-        if hit is not None:
-            est, version, path, ci_lo, ci_hi, n_eff = hit
-            # rel_width=0.0: an exact answer has NO smoothing — the proxy
-            # must rank it best, not worst (inf is reserved for genuinely
-            # unconstrained estimates)
-            results[c.slot] = AqpResult(
-                estimate=est, path=path, rel_width=0.0,
-                synopsis_version=version, group=c.group, query=c.query,
-                ci_lo=ci_lo, ci_hi=ci_hi, ci_level=ci_level,
-                n_effective=n_eff)
-        else:
-            remaining.append(c)
+    with obs.span("engine.exact", n=len(compiled)):
+        for c in compiled:
+            hit = try_exact(c) if try_exact is not None else None
+            if hit is not None:
+                est, version, path, ci_lo, ci_hi, n_eff = hit
+                # rel_width=0.0: an exact answer has NO smoothing — the proxy
+                # must rank it best, not worst (inf is reserved for genuinely
+                # unconstrained estimates)
+                results[c.slot] = AqpResult(
+                    estimate=est, path=path, rel_width=0.0,
+                    synopsis_version=version, group=c.group, query=c.query,
+                    ci_lo=ci_lo, ci_hi=ci_hi, ci_level=ci_level,
+                    n_effective=n_eff)
+            else:
+                remaining.append(c)
 
     # store-backed resolvers expose the owning store's registry and their
     # tier budget; the mapping resolver (execute_specs) has neither

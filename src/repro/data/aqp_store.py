@@ -1169,6 +1169,11 @@ class TelemetryStore:
             self._sessions.append(weakref.ref(session))
 
     def add_batch(self, stats: Dict[str, np.ndarray]) -> None:
+        rows = max((np.size(v) for v in stats.values()), default=0)
+        with obs.span("store.insert", rows=rows):
+            self._add_batch(stats)
+
+    def _add_batch(self, stats: Dict[str, np.ndarray]) -> None:
         # Build joint rows BEFORE mutating any reservoir: a ragged batch must
         # fail cleanly, not leave per-column reservoirs updated with the
         # joints skipped (partial mutation would silently skew every joint
@@ -1248,8 +1253,11 @@ class TelemetryStore:
         syn = self.cache.get(ckey, selector, res.version)
         if syn is None:
             data = res.sample() if tier is None else res.sample(tier)
-            syn = KDESynopsis.fit(data, selector=selector,
-                                  max_sample=self.capacity)
+            d = data.shape[1] if data.ndim > 1 else 1
+            with obs.span("synopsis.fit", n=data.shape[0], d=d,
+                          selector=selector):
+                syn = KDESynopsis.fit(data, selector=selector,
+                                      max_sample=self.capacity)
             # scale against the FULL stream: every tier is a uniform sample
             # of it, so tier answers are unbiased for the same relation
             syn.n_source = res.n_seen

@@ -84,6 +84,7 @@ def _aqp_batch_sums(x, h, a, b, tile, q_tile, interpret):
         out_specs=pl.BlockSpec((qk, 2), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((ap.shape[0], 2), x.dtype),
         interpret=interpret,
+        name="_aqp_batch_sums",
     )(ap, bp, xp, h.reshape(1).astype(x.dtype))
     return out[:q, 0], out[:q, 1]
 

@@ -102,6 +102,7 @@ def _aqp_box_sums(x, h_diag, lo, hi, tgt, tile, q_tile, interpret):
         out_specs=pl.BlockSpec((qk, 2), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((lop.shape[0], 2), x.dtype),
         interpret=interpret,
+        name="_aqp_box_sums",
     )(lop, hip, tgtp, xt, h_diag.astype(x.dtype))
     return out[:q, 0], out[:q, 1]
 

@@ -115,6 +115,7 @@ def _aqp_grouped_sums(x, h_diag, lo, hi, glo, ghi, g_axis, tgt, tile,
         out_specs=pl.BlockSpec((gk, 2), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((glop.shape[0], 2), x.dtype),
         interpret=interpret,
+        name="_aqp_grouped_sums",
     )(glop, ghip, xt, h_diag.astype(x.dtype), lo.astype(x.dtype),
       hi.astype(x.dtype))
     return out[:G, 0], out[:G, 1]

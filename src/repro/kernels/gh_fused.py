@@ -88,5 +88,6 @@ def _gh_fused_sum(x: jax.Array, h_inv: jax.Array, c_k, c_kk,
         out_specs=pl.BlockSpec((1, 1, _LANES), lambda bx: (bx, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((grid[0], 1, _LANES), x.dtype),
         interpret=interpret,
+        name="_gh_fused_sum",
     )(xp, xp, h_inv.astype(x.dtype), consts)
     return jnp.sum(partials[:, 0, 0])

@@ -82,6 +82,7 @@ def _kde_eval(points: jax.Array, x: jax.Array, h: jax.Array,
         out_specs=pl.BlockSpec((k, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pp.shape[0], 1), x.dtype),
         interpret=interpret,
+        name="_kde_eval",
     )(pp, xt, h.reshape(1).astype(x.dtype))
 
     norm = (2.0 * math.pi) ** (-d / 2.0) * h ** (-d)

@@ -105,5 +105,6 @@ def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
         out_specs=pl.BlockSpec((hk, _LANES), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((hinv.shape[0], _LANES), x.dtype),
         interpret=interpret,
+        name="_lscv_grid_sums",
     )(sp, w, hinv, consts)
     return out[:n_h, 0]

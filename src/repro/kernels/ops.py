@@ -16,8 +16,8 @@ With `repro.obs` enabled, every wrapper routes through
 `tuning.profiled_call`, which records fenced wall/dispatch timings into the
 process-global metrics registry keyed by (kernel, shape, tile, interpret) —
 so a run can show which kernels compiled and which were interpreted.
-Disabled (the default), each wrapper takes the direct branch — same jitted
-callable, no fencing, no extra work.
+Disabled (the default), and in `repro.obs` profiler mode, each wrapper
+takes the direct branch — same jitted callable, no fencing, no extra work.
 """
 from __future__ import annotations
 
@@ -38,7 +38,8 @@ from .tuning import interpret, profiled_call
 
 
 def _dispatch(kernel: str, run, **labels):
-    """Call `run(interpret)` directly, or profiled when `repro.obs` is on."""
+    """Call `run(interpret)` directly, or profiled (fenced) when
+    `repro.obs.enabled()`."""
     interp = interpret()
     if not obs.enabled():
         return run(interp)

@@ -86,5 +86,6 @@ def _pairwise_scaled_ksum(x: jax.Array, g: jax.Array, kind: str,
         out_specs=pl.BlockSpec((1, 1, _LANES), lambda bx: (bx, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((grid[0], 1, _LANES), x.dtype),
         interpret=interpret,
+        name="_pairwise_scaled_ksum",
     )(xp.reshape(-1, 1), xp.reshape(1, -1), g.reshape(1).astype(x.dtype))
     return jnp.sum(partials[:, 0, 0])

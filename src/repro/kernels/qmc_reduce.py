@@ -133,6 +133,7 @@ def _qmc_box_reduce(nodes, x, h_inv, log_norm, lo, hi, tgt, tile, m_tile,
         out_specs=pl.BlockSpec((qk, 2), lambda i, j, l: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((lop.shape[0], 2), x.dtype),
         interpret=interpret,
+        name="_qmc_box_reduce",
     )(lop, hip, tgtp, np_, np_.T, xt, h_inv.astype(x.dtype).reshape(-1),
       log_norm.reshape(1).astype(x.dtype))
     return out[:q, 0], out[:q, 1]

@@ -81,6 +81,7 @@ def _rff_density(points, w, b, z, tile, p_tile, interpret):
         out_specs=pl.BlockSpec((pk, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pp.shape[0], 1), dt),
         interpret=interpret,
+        name="_rff_density",
     )(pp, wt, bp, zp)
     return out[:m, 0]
 

@@ -98,5 +98,6 @@ def _sv_matrix(x: jax.Array, m: jax.Array, tile: int,
         out_specs=pl.BlockSpec((k, k), lambda q, l: (q, l)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], xp.shape[0]), x.dtype),
         interpret=interpret,
+        name="_sv_matrix",
     )(xp, xp, m.astype(x.dtype))
     return out[:n, :n]

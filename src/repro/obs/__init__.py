@@ -8,14 +8,21 @@ Two kinds of instrumentation with different cost profiles:
     aggregation bug fixable (closed sessions' counters persist in the
     store registry instead of dying with the session weakref).
 
-  * **Gated on `enabled()`**: span tracing, per-path latency histograms
-    with `block_until_ready` fencing, and kernel profiling.  Fencing
-    changes dispatch behaviour (it synchronises the device), so these are
-    opt-in: set ``REPRO_OBS=1`` in the environment or call
+  * **Gated on `enabled()`**: span tracing into the ring, per-path latency
+    histograms with `block_until_ready` fencing, and kernel profiling.
+    Fencing changes dispatch behaviour (it synchronises the device), so
+    these are opt-in: set ``REPRO_OBS=1`` in the environment or call
     :func:`enable` (e.g. ``serve --mode aqp --metrics-out ...`` does).
     When disabled, `span()` returns a shared no-op object and the kernel
     wrappers take the un-instrumented branch — zero extra jit traces and
     bit-identical numerics, both test-enforced.
+
+  * **Profiler mode** (:func:`trace_on_profiler`): each span opens a
+    `jax.profiler.TraceAnnotation` instead, so it lands in a
+    `jax.profiler` trace on the device trace's clock.  This mode never
+    fences: it takes precedence over `enable()`, `enabled()` reads False
+    while it is on, and the gated histograms and kernel profiling stay
+    off.  Device time comes from the device trace itself.
 
 Scoping: each `TelemetryStore` owns a registry (`store.metrics`) so tests
 and co-hosted stores stay isolated; kernel profiling and benchmarks write
@@ -35,23 +42,27 @@ from repro import knobs
 
 from .registry import (Counter, Gauge, Histogram, LATENCY_BUCKETS_US,
                        MetricsRegistry)
-from .trace import NOOP_SPAN, Span, Tracer
+from .trace import NOOP_SPAN, ProfilerSpan, Span, Tracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "LATENCY_BUCKETS_US", "MetricsRegistry",
-    "NOOP_SPAN", "Span", "Tracer", "disable", "enable", "enabled", "fence",
-    "export_json", "get_registry", "get_tracer", "set_tracer", "span",
+    "NOOP_SPAN", "ProfilerSpan", "Span", "Tracer", "disable", "enable",
+    "enabled", "fence", "export_json", "get_registry", "get_tracer",
+    "set_tracer", "span", "trace_on_profiler",
 ]
 
 _enabled = knobs.get_bool("REPRO_OBS")
+_profiler = False
+_annotation = None      # jax.profiler.TraceAnnotation, once profiler mode is on
 _registry = MetricsRegistry()
 _tracer = Tracer()
 
 
 def enabled() -> bool:
-    """True when the expensive instrumentation (tracing, fenced latency
-    histograms, kernel profiling) is active."""
-    return _enabled
+    """True when the fenced instrumentation (ring tracing, fenced latency
+    histograms, kernel profiling) is active: after `enable()`, and not in
+    profiler mode."""
+    return _enabled and not _profiler
 
 
 def enable() -> None:
@@ -62,6 +73,24 @@ def enable() -> None:
 def disable() -> None:
     global _enabled
     _enabled = False
+
+
+def trace_on_profiler(on: bool = True) -> None:
+    """Switch profiler mode on or off.
+
+    On, every `span()` opens a `jax.profiler.TraceAnnotation` named after
+    the span for its lifetime; its int and float attributes, given at open
+    or later through `set()`, become the event's stats (strings and None
+    stay out).  The events are recorded only while a `jax.profiler` trace
+    runs, on the thread that opened the span.  Nothing fences, `enabled()`
+    reads False, and the ring records nothing.  `jax.profiler` is imported
+    here, on first use, so this package still imports only the standard
+    library."""
+    global _profiler, _annotation
+    if on and _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    _profiler = bool(on)
 
 
 def get_registry() -> MetricsRegistry:
@@ -81,21 +110,29 @@ def set_tracer(tracer: Tracer) -> Tracer:
     return prev
 
 
-def span(name: str, parent: Optional[Tuple[int, int]] = None, **attrs):
-    """Open a span on the global tracer, or the shared no-op when disabled.
+def span(name: str, parent: Optional[Tuple[int, int]] = None,
+         root: bool = False, **attrs):
+    """Open a span: a profiler annotation in profiler mode, else a span on
+    the global tracer, or the shared no-op when both are off.
 
     The no-op singleton means a disabled `with obs.span(...):` costs one
-    function call and no allocation."""
+    function call and no allocation; no annotation is built then either.
+    `parent` links ring spans across threads and `root` starts a new trace
+    (see `Tracer.span`); the profiler places its events by thread and time
+    and takes neither."""
+    if _profiler:
+        return ProfilerSpan(_annotation, name, attrs)
     if not _enabled:
         return NOOP_SPAN
-    return _tracer.span(name, parent=parent, **attrs)
+    return _tracer.span(name, parent=parent, root=root, **attrs)
 
 
 def fence(*values) -> None:
     """Block until every jax array in `values` is device-ready, so the
     enclosing span measures real device time rather than dispatch time.
-    Non-jax values pass through silently; no-op when disabled."""
-    if not _enabled:
+    Non-jax values pass through silently; no-op unless `enabled()` (so
+    never in profiler mode)."""
+    if not _enabled or _profiler:
         return
     for v in values:
         bur = getattr(v, "block_until_ready", None)

@@ -23,6 +23,10 @@ Design points:
 Timing inside a span is only *device-true* if the caller fences (see
 `repro.obs.fence`); the engine instrumentation calls `block_until_ready`
 on kernel outputs before closing kernel spans.
+
+In profiler mode (`repro.obs.trace_on_profiler`) the sink is the
+`jax.profiler` trace instead of the ring: `ProfilerSpan` holds one
+`TraceAnnotation`, on the device trace's clock, and nothing fences.
 """
 from __future__ import annotations
 
@@ -114,13 +118,45 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+def _numeric(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: v for k, v in attrs.items() if type(v) in (int, float)}
+
+
+class ProfilerSpan:
+    """Profiler-mode span: a `jax.profiler.TraceAnnotation` held open for
+    the span's lifetime.  Its stats are the int and float attributes given
+    at open and through `set()` (strings stay in the code, not the trace).
+    `ctx` is None, as for the no-op: the profiler places events by thread
+    and time, so a span on another thread takes no explicit parent."""
+
+    __slots__ = ("_annotation",)
+    ctx = None
+
+    def __init__(self, annotation_cls, name: str, attrs: Dict[str, Any]):
+        self._annotation = annotation_cls(name, **_numeric(attrs))
+
+    def set(self, **attrs) -> "ProfilerSpan":
+        stats = _numeric(attrs)
+        if stats:
+            self._annotation.set_metadata(**stats)
+        return self
+
+    def __enter__(self) -> "ProfilerSpan":
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
+
+
 class Tracer:
     """Bounded span recorder.
 
     `span(name, parent=..., **attrs)` opens a span whose parent is, in
     order of preference: the explicit `parent` ctx tuple, else the current
     span in this execution context, else none (a new root — which also
-    mints a fresh trace id).
+    mints a fresh trace id).  `root=True` skips the current span, for a
+    span that starts its own trace wherever it is opened.
     """
 
     def __init__(self, clock=time.perf_counter, capacity: int = 4096):
@@ -129,15 +165,14 @@ class Tracer:
         self._lock = threading.Lock()
 
     def span(self, name: str, parent: Optional[Tuple[int, int]] = None,
-             **attrs) -> Span:
+             root: bool = False, **attrs) -> Span:
+        cur = None if parent is not None or root else _CURRENT.get()
         if parent is not None:
             trace_id, parent_id = parent
+        elif cur is not None:
+            trace_id, parent_id = cur.trace_id, cur.span_id
         else:
-            cur = _CURRENT.get()
-            if cur is not None:
-                trace_id, parent_id = cur.trace_id, cur.span_id
-            else:
-                trace_id, parent_id = next(_ids), None
+            trace_id, parent_id = next(_ids), None
         return Span(self, name, trace_id, parent_id, attrs)
 
     def current(self) -> Optional[Span]:

@@ -140,8 +140,9 @@ def test_disabled_span_is_shared_noop():
 
 def test_span_tree_reconstructs_admission_to_kernel_path(enabled, rng):
     """One traced query yields the full tree: admission.submit ->
-    admission.flush -> engine.run_compiled -> {engine.plan, engine.kernel,
-    engine.ci}, with the path recorded on the kernel span."""
+    {engine.compile, engine.key, admission.flush -> engine.run_compiled ->
+    {engine.exact, engine.plan, engine.kernel, engine.ci, engine.fetch}}, with
+    the path recorded on the kernel span."""
     tracer = enabled
     store = _store(rng)
     engine = store.engine()
@@ -156,14 +157,20 @@ def test_span_tree_reconstructs_admission_to_kernel_path(enabled, rng):
     assert len(submit) == 1
     tree = tracer.tree(submit[0].trace_id)
     assert [n["name"] for n in tree] == ["admission.submit"]
-    flush = tree[0]["children"]
-    assert [n["name"] for n in flush] == ["admission.flush"]
+    assert tree[0]["attrs"]["parts"] == "1"
+    children = tree[0]["children"]
+    assert [n["name"] for n in children] == [
+        "engine.compile", "engine.key", "admission.flush"]
+    flush = children[2:]
     assert flush[0]["attrs"]["reason"] == "manual"
+    assert flush[0]["attrs"]["batch"] == "1"
+    assert float(flush[0]["attrs"]["wait_us"]) >= 0.0
     run = flush[0]["children"]
-    assert [n["name"] for n in run] == ["engine.run_compiled"]
+    assert [n["name"] for n in run] == ["engine.run_compiled",
+                                        "admission.resolve"]
     names = [n["name"] for n in run[0]["children"]]
-    assert names[0] == "engine.plan"
-    assert "engine.kernel" in names and "engine.ci" in names
+    assert names == ["engine.exact", "engine.plan", "engine.kernel",
+                     "engine.ci", "engine.fetch"]
     kernel = next(n for n in run[0]["children"]
                   if n["name"] == "engine.kernel")
     assert kernel["attrs"]["path"] == "range1d"
@@ -267,7 +274,8 @@ def test_disabled_admission_counters_still_live(rng):
         assert st["submitted"] == 1 and st["flushes"] == 1
     # but the gated latency histogram stayed empty
     assert store.metrics.sum_histogram("aqp.query.latency_us")[1] == 0
-    assert store.metrics.sum_histogram("aqp.admission.flush_us")[1] == 0
+    assert store.metrics.sum_histogram("aqp.query.latency_us",
+                                       path="range1d")[1] == 0
 
 
 # --- export -------------------------------------------------------------------

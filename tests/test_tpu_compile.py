@@ -5,7 +5,9 @@ Each kernel is lowered with `interpret=False` for one chip of a described
 reservoirs, d=2, batches padded pow2-then-x64 below and above one query
 tile, 4,096 QMC nodes, 2,048 RFF features, the 150-point LSCV_h grid) and
 compiled by the installed TPU compiler; the compiled program must contain
-the Mosaic kernel (`tpu_custom_call`).  Interpret-mode tests cannot see
+the Mosaic kernel (`tpu_custom_call`) under the kernel's stable name (the
+`name=` of its `pallas_call`, which a profiler trace shows as the
+operation's name).  Interpret-mode tests cannot see
 what this catches: unaligned blocks, primitives without a Mosaic lowering,
 layouts XLA and Mosaic disagree on.
 
@@ -13,6 +15,8 @@ The topology is described inside a module fixture (never at import): only
 the worker that runs this file loads the TPU library, and every worker
 collects the same tests.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -110,11 +114,23 @@ def _cases():
 
 CASES = _cases()
 
+# the operation name each kernel keeps in a profiler trace
+KERNEL_NAMES = {
+    "aqp_batch": "_aqp_batch_sums", "aqp_boxes": "_aqp_box_sums",
+    "qmc_reduce": "_qmc_box_reduce", "aqp_grouped": "_aqp_grouped_sums",
+    "rff_eval": "_rff_density", "kde_eval": "_kde_eval",
+    "pairwise_reduce": "_pairwise_scaled_ksum", "sv_precompute": "_sv_matrix",
+    "gh_fused": "_gh_fused_sum", "lscv_grid": "_lscv_grid_sums",
+}
+
 
 @pytest.mark.parametrize("name,fn,shapes", CASES,
                          ids=[c[0] for c in CASES])
 def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), name
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, name
+    kernel = KERNEL_NAMES[name.split("-")[0]]
+    assert re.search(rf"%{kernel}\.\d+ = .*custom_call_target=\"tpu_custom_call\"",
+                     text), (name, kernel)

@@ -321,9 +321,10 @@ def lscv_h_override(store, backend: str, cols):
 
 
 def kernels_vs_jnp(store) -> dict:
-    """The kernels the serving path does not dispatch, each against its jnp
-    counterpart on the chip: PLUGIN (pairwise_reduce) on a full reservoir,
-    kde_eval, and the LSCV_H objective (gh_fused) at the fitted H."""
+    """Fit-side kernels, each against its jnp counterpart on the chip:
+    PLUGIN (pairwise_reduce, which a pallas engine's fits also run) on a
+    full reservoir, kde_eval, and the LSCV_H objective (gh_fused) at the
+    fitted H."""
     import jax.numpy as jnp
     import numpy as np
 

@@ -671,7 +671,8 @@ class AqpSession:
             return
         colkey, sel, tier, version = key
         try:
-            resolver = session.engine.resolver(sel, tier=tier)
+            resolver = session.engine.resolver(sel, tier=tier,
+                                               backend=session.backend)
             with obs.span("admission.fit", key=colkey, selector=sel,
                           tier=tier, session=session.sid):
                 resolver.plan_for((colkey, sel, tier), version)

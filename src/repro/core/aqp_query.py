@@ -532,11 +532,12 @@ class _StoreResolver:
 
     def __init__(self, store, selector: str,
                  plans: Optional[PlanCache] = None,
-                 tier: Optional[int] = None):
+                 tier: Optional[int] = None, backend: str = "jnp"):
         self.store = store
         self.selector = selector
         self.plans = plans
         self.tier = tier
+        self.backend = backend
 
     def key_for(self, c: _Compiled):
         """(group key, reordered compiled, reservoir version) — no fitting."""
@@ -575,9 +576,11 @@ class _StoreResolver:
                 return plan
         col, sel, tier = key
         if isinstance(col, tuple):
-            syn = self.store.joint_synopsis(col, sel, tier=tier)
+            syn = self.store.joint_synopsis(col, sel, tier=tier,
+                                            backend=self.backend)
         else:
-            syn = self.store.synopsis(col, sel, tier=tier)
+            syn = self.store.synopsis(col, sel, tier=tier,
+                                      backend=self.backend)
         plan = _make_plan(syn)
         if self.plans is not None:
             self.plans.put(key, version, plan)
@@ -1119,12 +1122,15 @@ class QueryEngine:
         return compiled
 
     def resolver(self, selector: Optional[str] = None,
-                 tier: Optional[int] = None) -> _StoreResolver:
+                 tier: Optional[int] = None,
+                 backend: Optional[str] = None) -> _StoreResolver:
         """Store resolver wired to this engine's version-keyed plan cache.
         `tier` budgets resolution to one tier of a `TieredReservoir` (None =
-        the full sample; plain reservoirs ignore it)."""
+        the full sample; plain reservoirs ignore it); `backend` (default the
+        engine's) runs the PLUGIN fits it triggers."""
         return _StoreResolver(self.store, selector or self.selector,
-                              plans=self.plans, tier=tier)
+                              plans=self.plans, tier=tier,
+                              backend=backend or self.backend)
 
     def run_compiled(self, compiled: Sequence[_Compiled],
                      selector: Optional[str] = None,
@@ -1136,7 +1142,8 @@ class QueryEngine:
         with obs.span("engine.run_compiled", n=len(compiled), tier=tier,
                       backend=backend or self.backend):
             return _execute(compiled, len(compiled),
-                            self.resolver(selector, tier=tier),
+                            self.resolver(selector, tier=tier,
+                                          backend=backend),
                             backend=backend or self.backend, n_qmc=self.n_qmc,
                             ci_level=self.ci_level,
                             kde_backend=kde_backend or self.kde_backend)

@@ -1222,28 +1222,38 @@ class TelemetryStore:
                 (time.perf_counter() - t_ingest) * 1e6)
 
     def synopsis(self, column: str, selector: str = "plugin",
-                 tier: Optional[int] = None) -> KDESynopsis:
+                 tier: Optional[int] = None,
+                 backend: str = "jnp") -> KDESynopsis:
+        """A column's synopsis; `backend` as for `joint_synopsis`."""
         res = self.columns.get(column)
         if res is None:
             raise KeyError(f"unknown column {column!r}; "
                            f"have {sorted(self.columns)}")
-        return self._fit_cached(column, res, selector, tier=tier)
+        return self._fit_cached(column, res, selector, tier=tier,
+                                backend=backend)
 
     def joint_synopsis(self, columns: Sequence[str],
                        selector: str = "plugin",
-                       tier: Optional[int] = None) -> KDESynopsis:
+                       tier: Optional[int] = None,
+                       backend: str = "jnp") -> KDESynopsis:
         """Joint synopsis over a tracked column tuple: per-axis diagonal
-        bandwidths (plugin/silverman), scalar LSCV_h, or full-H LSCV_H."""
+        bandwidths (plugin/silverman), scalar LSCV_h, or full-H LSCV_H.
+
+        `backend` ("jnp" | "pallas") runs the PLUGIN pair sums; the other
+        selectors fit as they always have.  A cached synopsis serves either
+        backend: both compute the same estimator to float32 rounding."""
         key = tuple(columns)
         res = self.joints.get(key)
         if res is None:
             raise KeyError(f"no joint reservoir for columns {key!r}; call "
                            f"track_joint({key!r}) before add_batch "
                            f"(have {sorted(self.joints)})")
-        return self._fit_cached(key, res, selector, tier=tier)
+        return self._fit_cached(key, res, selector, tier=tier,
+                                backend=backend)
 
     def _fit_cached(self, key: ColumnKey, res: Reservoir, selector: str,
-                    tier: Optional[int] = None) -> KDESynopsis:
+                    tier: Optional[int] = None,
+                    backend: str = "jnp") -> KDESynopsis:
         # lazy import: aqp_query imports this module's types at top level
         from repro.core.aqp_query import _effective_tier, _tier_key
 
@@ -1254,10 +1264,15 @@ class TelemetryStore:
         if syn is None:
             data = res.sample() if tier is None else res.sample(tier)
             d = data.shape[1] if data.ndim > 1 else 1
+            # only PLUGIN follows the caller's backend: the Pallas LSCV_h
+            # fit holds an n x n matrix in HBM
+            backend = backend if selector == "plugin" else "jnp"
             with obs.span("synopsis.fit", n=data.shape[0], d=d,
-                          selector=selector):
+                          selector=selector, backend=backend,
+                          pallas=int(backend == "pallas")):
                 syn = KDESynopsis.fit(data, selector=selector,
-                                      max_sample=self.capacity)
+                                      max_sample=self.capacity,
+                                      backend=backend)
             # scale against the FULL stream: every tier is a uniform sample
             # of it, so tier answers are unbiased for the same relation
             syn.n_source = res.n_seen

@@ -178,9 +178,9 @@ register("REPRO_RFF_TILE", "int", 256,
 register("REPRO_RFF_P_TILE", "int", 128,
          "Point-tile size of the rff_density Pallas kernel.")
 
-register("REPRO_PAIRWISE_TILE", "int", 256,
-         "Data-tile size of the pairwise_scaled_ksum Pallas kernel "
-         "(PLUGIN selector inner sums).")
+register("REPRO_PAIRWISE_TILE", "int", 4096,
+         "Square tile side of the pairwise_scaled_ksum Pallas kernel "
+         "(PLUGIN selector inner sums), rounded up to a power of two.")
 register("REPRO_SV_TILE", "int", 256,
          "Data-tile size of the sv_matrix Pallas kernel (LSCV_H "
          "precompute).")

@@ -502,6 +502,66 @@ def test_engine_pallas_backend_paths(rng, backend):
     np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-2)
 
 
+@pytest.fixture
+def fit_spans():
+    """Ring tracing on for one test, with a fresh tracer: yields a function
+    that lists the `synopsis.fit` spans' (selector, backend)."""
+    from repro import obs
+    from repro.obs import Tracer
+    prev, was = obs.set_tracer(Tracer()), obs.enabled()
+    obs.enable()
+    yield lambda: [(sp.attrs["selector"], sp.attrs["backend"])
+                   for sp in obs.get_tracer().spans()
+                   if sp.name == "synopsis.fit"]
+    if not was:
+        obs.disable()
+    obs.set_tracer(prev)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_plugin_refit_follows_engine_backend(rng, monkeypatch, fit_spans,
+                                             backend):
+    """A refit after add_batch runs the PLUGIN pair sums on the Pallas
+    kernel exactly when the engine's backend is pallas."""
+    from repro.core.plugin import plugin_bandwidth
+    from repro.kernels import ops as kops
+    kinds = []
+    real = kops.pairwise_scaled_ksum
+
+    def spy(x, g, kind="k4", tile=None):
+        kinds.append(kind)
+        return real(x, g, kind=kind, tile=tile)
+
+    monkeypatch.setattr(kops, "pairwise_scaled_ksum", spy)
+    store, a, b, code = _store(rng, n=4000, capacity=256)
+    eng = store.engine(backend=backend)
+    spec = AqpQuery("count", (Range("a", -1.0, 1.0),))
+    eng.execute(spec)
+    store.add_batch({"a": a[:500], "b": b[:500], "code": code[:500]})
+    plugin_bandwidth.clear_cache()      # the spy is seen when the refit traces
+    kinds.clear()
+    res = eng.execute(spec)[0]
+    assert fit_spans()[-1] == ("plugin", backend)
+    assert kinds == (["k6", "k4"] if backend == "pallas" else [])
+    want = store.synopsis("a").h    # the cached fit, whichever backend ran
+    h = plugin_bandwidth(store.columns["a"].sample(), backend="jnp").h
+    assert abs(float(want) / float(h) - 1) < 1e-4
+    assert np.isfinite(res.estimate)
+
+
+def test_lscv_fit_ignores_engine_backend(rng, fit_spans):
+    """LSCV_h keeps its jnp fit whatever the engine's backend: the same
+    data gives the same bandwidth under either engine."""
+    spec = AqpQuery("count", (Range("a", -1.0, 1.0),), selector="lscv_h")
+    hs = []
+    for backend in ("jnp", "pallas"):
+        store, *_ = _store(np.random.default_rng(3), n=4000, capacity=256)
+        store.engine(backend=backend).execute(spec)
+        hs.append(float(store.synopsis("a", "lscv_h").h))
+    assert hs[0] == hs[1]
+    assert [b for sel, b in fit_spans()] == ["jnp", "jnp"]
+
+
 # --- batched QMC fallback ----------------------------------------------------
 
 def test_batched_qmc_matches_per_query_loop(rng):

@@ -30,15 +30,21 @@ else:
         _check_triangle_roundtrip(bx)
 
 
-@pytest.mark.parametrize("n", [5, 64, 257, 1000])
+# (n, tile): below one tile (5, 64), several triangle tiles with a ragged
+# last one (257, 1000, 2500), an exact multiple (384); from 1,024 on, a
+# diagonal tile runs in 1,024-wide bands (2500, and 4096 / 3000 with two
+# bands, the latter with a ragged off-diagonal column)
+@pytest.mark.parametrize("n,tile", [(5, 128), (64, 128), (257, 128),
+                                    (1000, 128), (384, 128), (2500, 1024),
+                                    (4096, 2048), (3000, 2048)])
 @pytest.mark.parametrize("kind", ["k4", "k6", "gauss"])
-def test_pairwise_ksum(n, kind):
+def test_pairwise_ksum(n, tile, kind):
     # dedicated per-case generator: K^(6) pair sums can cancel towards zero,
     # so the comparison needs deterministic data + a |sum|-scaled atol.
     local = np.random.default_rng(1234 + n)
     x = jnp.asarray(local.normal(0, 1, n).astype(np.float32))
     g = jnp.float32(0.4)
-    a = ops.pairwise_scaled_ksum(x, g, kind=kind, tile=64)
+    a = ops.pairwise_scaled_ksum(x, g, kind=kind, tile=tile)
     b = ref.pairwise_scaled_ksum(x, g, kind)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=3e-4,
                                atol=max(1e-5, 1e-6 * n))
@@ -86,10 +92,11 @@ def test_kde_eval(rng, m, n, d):
 
 
 def test_kernels_match_at_tile_boundaries(rng):
-    """Exercise n == tile, n == tile+1, n == 2*tile-1 edge shapes."""
-    for n in [64, 65, 127, 128]:
+    """Exercise n == tile, n == tile+1, n == 2*tile-1 edge shapes (128 is
+    the smallest tile the kernel takes)."""
+    for n in [128, 129, 255, 256]:
         x = jnp.asarray(rng.normal(0, 1, n).astype(np.float32))
-        a = ops.pairwise_scaled_ksum(x, jnp.float32(0.5), kind="k4", tile=64)
+        a = ops.pairwise_scaled_ksum(x, jnp.float32(0.5), kind="k4", tile=128)
         b = ref.pairwise_scaled_ksum(x, jnp.float32(0.5), "k4")
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=3e-4, atol=1e-5)
 

@@ -8,17 +8,20 @@ Numbers (limits in `limits/<config>.json`):
               |d AVG| C / (rows M), with M = max |x_target| + h_target over
               the sample and C the reference count
   ci_gap      the same for the 95% interval's half-width
-  h_gap       largest relative gap of a PLUGIN bandwidth (every axis of
-              every synopsis fitted at its column's final version)
+  h_gap       largest gap of a bandwidth, as the estimator module measures
+              it (plugin: relative, per axis), over every synopsis fitted at
+              its column's final version
   exact_gap   largest |answer - exact count| of a sampled Eq answer
   unanswered  queries due in the window (and probes) that never got an
               answer, or got an error
   stale       probes answered on a synopsis version older than the refresh
               they followed
 
-In control mode the reference computed in bfloat16 takes the program's place
-(estimates, intervals, bandwidths and exact counts): it must come out not
-correct.
+The bandwidths and KDE answers come from the estimator module of the
+configuration's `engine.selector` (`estimators/<selector>.py`), handed in as
+`est`.  In control mode the reference computed in bfloat16 takes the
+program's place (estimates, intervals, bandwidths and exact counts): it must
+come out not correct.
 
 Besides the numbers, `readings` names the worst KDE answer and the worst
 exact answer, each with its gap against the reference at the synopsis
@@ -54,8 +57,9 @@ def _half(r) -> float:
     return (r.ci_hi - r.ci_lo) / 2.0
 
 
-def readings(work: dict, control: Optional[str] = None) -> dict:
-    """Compare a run's sampled answers with the reference.
+def readings(work: dict, est, control: Optional[str] = None) -> dict:
+    """Compare a run's sampled answers with the reference of estimator
+    module `est`.
 
     `work` holds: the store's configuration, the first batch and the refresh
     batches, the sampled answers [(spec, result, key, version)] and exact
@@ -91,24 +95,19 @@ def readings(work: dict, control: Optional[str] = None) -> dict:
                                        res[k].writes)
 
     prec = ref.Precision(control) if control else None
-    h_ref: Dict[tuple, np.ndarray] = {}
-    h_ctl: Dict[tuple, np.ndarray] = {}
+    h_ref: Dict[tuple, object] = {}
+    h_ctl: Dict[tuple, object] = {}
     for kv in keys_versions:
-        x2 = samples[kv][0].reshape(samples[kv][0].shape[0], -1)
-        h_ref[kv] = np.asarray([ref.plugin_h(x2[:, j])
-                                for j in range(x2.shape[1])])
+        h_ref[kv] = est.bandwidth(samples[kv][0], ref.F64)
         if prec is not None:
-            h_ctl[kv] = np.asarray([ref.plugin_h(x2[:, j], prec)
-                                    for j in range(x2.shape[1])])
+            h_ctl[kv] = est.bandwidth(samples[kv][0], prec)
 
     # bandwidths
     h_gap = 0.0
     for kv, h_p in work["h_prog"].items():
-        h_r = h_ref[kv]
         if prec is not None:
             h_p = h_ctl[kv]
-        h_gap = max(h_gap, float(np.max(np.abs(np.asarray(h_p, np.float64)
-                                               - h_r) / h_r)))
+        h_gap = max(h_gap, est.bandwidth_gap(h_p, h_ref[kv]))
 
     # KDE answers, grouped by synopsis and version
     by_kv = defaultdict(list)
@@ -121,9 +120,9 @@ def readings(work: dict, control: Optional[str] = None) -> dict:
         cols = kv[0] if isinstance(kv[0], tuple) else (kv[0],)
         boxes = [ref.box_of(spec, cols, r.group) for spec, r, _k, _v in items]
         aggs = [spec["agg"] for spec, _r, _k, _v in items]
-        truth = ref.kde_answers(boxes, aggs, x, h_ref[kv], n_seen)
+        truth = est.answers(boxes, aggs, x, h_ref[kv], n_seen, ref.F64)
         if prec is not None:
-            got = ref.kde_answers(boxes, aggs, x, h_ctl[kv], n_seen, prec)
+            got = est.answers(boxes, aggs, x, h_ctl[kv], n_seen, prec)
         else:
             got = [(r.estimate, _half(r)) for _s, r, _k, _v in items]
         for (spec, r, _k, _v), t, g in zip(items, truth, got):
@@ -145,9 +144,9 @@ def readings(work: dict, control: Optional[str] = None) -> dict:
             ci_gap = max(ci_gap, c)
 
     if worst_item is not None and control is None:
-        worst["gap_at"] = _gaps_at_neighbours(worst_item, samples)
+        worst["gap_at"] = _gaps_at_neighbours(worst_item, samples, est)
         worst["torn"] = _torn_scan(worst_item, samples,
-                                   h_ref[worst_item[2]])
+                                   h_ref[worst_item[2]], est)
 
     # exact answers
     def exact_truth(spec, version):
@@ -176,15 +175,15 @@ def readings(work: dict, control: Optional[str] = None) -> dict:
                    "exact_gap": exact_gap,
                    "unanswered": float(work["unanswered"]),
                    "stale": float(work["stale"])},
-        "compared": {"kde_answers": len(work["kde"]),
-                     "exact_answers": len(work["exact"]),
+        "compared": {"kde": len(work["kde"]),
+                     "exact": len(work["exact"]),
                      "bandwidths": len(work["h_prog"]),
                      "versions": len(versions)},
         "worst": {"estimate": worst, "exact": worst_exact},
     }
 
 
-def _gaps_at_neighbours(item, samples) -> Dict[str, float]:
+def _gaps_at_neighbours(item, samples, est) -> Dict[str, float]:
     """The gap of one KDE answer against the reference at the synopsis
     versions next to the one it carries (where the window has them)."""
     spec, r, (key, version) = item
@@ -194,28 +193,23 @@ def _gaps_at_neighbours(item, samples) -> Dict[str, float]:
         if (key, v) not in samples:
             continue
         x, n_seen, _writes = samples[(key, v)]
-        est, _hw, count, m_t = ref.kde_answers(
-            [ref.box_of(spec, cols, r.group)], [spec["agg"]], x, _plugin(x),
-            n_seen)[0]
-        out[f"v{v}"] = gap(spec["agg"], r.estimate - est, n_seen, m_t, count)
+        e, _hw, count, m_t = est.answers(
+            [ref.box_of(spec, cols, r.group)], [spec["agg"]], x,
+            est.bandwidth(x, ref.F64), n_seen, ref.F64)[0]
+        out[f"v{v}"] = gap(spec["agg"], r.estimate - e, n_seen, m_t, count)
     return out
-
-
-def _plugin(x: np.ndarray) -> np.ndarray:
-    x2 = x.reshape(x.shape[0], -1)
-    return np.asarray([ref.plugin_h(x2[:, j]) for j in range(x2.shape[1])])
 
 
 TORN_MAX_WRITES = 4096
 
 
-def _torn_scan(item, samples, h: np.ndarray) -> Optional[dict]:
+def _torn_scan(item, samples, h, est) -> Optional[dict]:
     """The reference over every buffer state from version v - 1 to v + 1,
     one write of the adds that made v and v + 1 at a time, in the order the
     reservoir makes them, with v's bandwidth and each version's rows-seen
     count: the state nearest the program's answer (`offset` -j: j writes of
     insert v still missing; +j: j writes of insert v + 1 already in), its
-    gap, and the gap again with that state's own PLUGIN bandwidth."""
+    gap, and the gap again with that state's own bandwidth."""
     spec, r, (key, version) = item
     cols = key if isinstance(key, tuple) else (key,)
     box = ref.box_of(spec, cols, r.group)
@@ -241,20 +235,21 @@ def _torn_scan(item, samples, h: np.ndarray) -> Optional[dict]:
             states.append(buf.copy())
     seen = {f"v{v}": samples[(key, v)][1] for v in
             (version - 1, version, version + 1) if (key, v) in samples}
-    _e, _h, count_v, m_v = ref.kde_answers([box], [spec["agg"]], x_v, h,
-                                           n_v)[0]
+    _e, _h, count_v, m_v = est.answers([box], [spec["agg"]], x_v, h, n_v,
+                                       ref.F64)[0]
     best = None
     for off, x in zip(offsets, states):
-        est = ref.kde_answers([box], [spec["agg"]], x, h, n_v)[0][0]
+        a = est.answers([box], [spec["agg"]], x, h, n_v, ref.F64)[0][0]
         for name, n in seen.items():
-            e = est if spec["agg"] == "avg" else est * n / n_v
+            e = a if spec["agg"] == "avg" else a * n / n_v
             g = gap(spec["agg"], r.estimate - e, n_v, m_v, count_v)
             if best is None or g < best[0]:
                 best = (g, off, name, x, n)
     g, off, name, x, n = best
-    est = ref.kde_answers([box], [spec["agg"]], x, _plugin(x), n)[0][0]
+    a = est.answers([box], [spec["agg"]], x, est.bandwidth(x, ref.F64), n,
+                    ref.F64)[0][0]
     return {"offset": off, "n_seen_of": name, "gap": g,
-            "gap_own_h": gap(spec["agg"], r.estimate - est, n_v, m_v,
+            "gap_own_h": gap(spec["agg"], r.estimate - a, n_v, m_v,
                              count_v),
             "writes": [int(writes_v[0].size),
                        int(after[2][0].size) if after is not None else 0]}

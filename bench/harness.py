@@ -46,11 +46,15 @@ def load_benchmark(root: str = ROOT) -> dict:
     return load_json(root, "BENCHMARK.json")
 
 
-def load_module(kind: str, name: str):
-    """`bench/<kind>/<name>.py` by file name (names may hold '.' and '-')."""
-    path = os.path.join(HERE, kind, f"{name}.py")
-    mod_name = f"bench_{kind}_" + "".join(ch if ch.isalnum() else "_"
-                                         for ch in name)
+def load_module(kind: str, name: str, root: str = HERE):
+    """`<root>/<kind>/<name>.py` by file name (names may hold '.' and '-');
+    `root` is the benchmark's directory."""
+    path = os.path.join(root, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} module {name!r}: {path} is "
+                                f"missing")
+    mod_name = "".join(ch if ch.isalnum() else "_"
+                       for ch in os.path.relpath(path[:-3], ROOT))
     if mod_name in sys.modules:
         return sys.modules[mod_name]
     spec = importlib.util.spec_from_file_location(mod_name, path)
@@ -117,7 +121,10 @@ def warm(engine, templates: List[dict], rng, stats, sizes) -> int:
 
 
 class LayerContext:
-    """What per-layer metric readers see."""
+    """What per-layer metric readers see.  Layer files, cost entries and
+    cost modules are read from `root`, the benchmark's directory."""
+
+    root = HERE
 
     def __init__(self, trace, answered, queries_done, refreshes, admission,
                  n, peak):
@@ -130,10 +137,22 @@ class LayerContext:
         self.peak = peak
 
     def layer(self, name: str) -> dict:
-        return load_json(HERE, "layers", f"{name}.json")
+        return load_json(self.root, "layers", f"{name}.json")
+
+    def costs(self, layer: str) -> List[dict]:
+        """The layer's cost entries: the `costs` of `layers/<layer>.json`,
+        then one entry per file of `layers/<layer>.costs/`, in name order.
+        An entry names a program, a `cost` module and, for queries, the
+        answer `path` it counts."""
+        entries = list(self.layer(layer).get("costs", ()))
+        more = os.path.join(self.root, "layers", f"{layer}.costs")
+        if os.path.isdir(more):
+            entries += [load_json(more, f) for f in sorted(os.listdir(more))
+                        if f.endswith(".json")]
+        return entries
 
     def cost(self, name: str):
-        return load_module("cost", name)
+        return load_module("cost", name, self.root)
 
 
 _SLOW_EVENTS: List[tuple] = []
@@ -188,6 +207,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool,
         cfg[k].update(v) if isinstance(v, dict) else cfg.__setitem__(k, v)
     traffic.update(overrides.get("traffic", {}))
     limits = load_json(HERE, "limits", f"{cfg['name']}.json")
+    # the check follows the configuration's selector: a selector with no
+    # estimator module stops the run here, before any data is made
+    est = load_module("estimators", cfg["engine"]["selector"])
     e2e_names = [m["name"] for m in bench["end_to_end"]
                  if cell_name in m.get("workloads", [cell_name])]
     layer_names = [m["name"] for m in bench["per_layer"]
@@ -407,8 +429,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool,
                    else store.columns[key])
         syn = store.cache.peek(key, eng_cfg["selector"], res_obj.version)
         if syn is not None and res_obj.version == final_version:
-            h_prog[(key, res_obj.version)] = np.asarray(syn.h_diag(),
-                                                        np.float64)
+            h_prog[(key, res_obj.version)] = est.program_bandwidth(syn)
     session.close()
     del session, engine, store, queries, probes
     gc.collect()
@@ -417,9 +438,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, traced: bool,
             "kde": sampled["kde"], "exact": sampled["exact"],
             "h_prog": h_prog, "unanswered": unanswered, "stale": stale}
     t_ref = time.perf_counter()
-    checked = compare.readings(work, control=control)
+    checked = compare.readings(work, est, control=control)
     also = overrides.get("also_control")
-    control_readings = (compare.readings(work, control=also)["values"]
+    control_readings = (compare.readings(work, est, control=also)["values"]
                         if also else None)
     qual = compare.quality(sampled["kde"][:QUALITY_SAMPLE], data, batches)
     log(f"reference check {time.perf_counter() - t_ref:.2f} s over "

@@ -17,11 +17,10 @@ def layer_seconds(ctx, layer: str) -> Optional[float]:
 
 def work(ctx, layer: str) -> Tuple[float, float]:
     """(operations, bytes) the layer's algorithms needed in the window,
-    counted from shapes by the layer's cost modules."""
-    spec = ctx.layer(layer)
+    counted from shapes by the cost modules of the layer's cost entries."""
     flops = nbytes = 0.0
     calls = ctx.trace["program_calls"]
-    for ent in spec.get("costs", ()):
+    for ent in ctx.costs(layer):
         cost = ctx.cost(ent["cost"])
         n_calls = calls.get(ent["program"], 0)
         if "path" in ent:

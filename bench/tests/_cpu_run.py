@@ -5,9 +5,10 @@ import time
 from bench import harness
 
 
-def small_run(cell, seconds=2.0, control=None, seed=20241016):
+def small_run(cell, seconds=2.0, control=None, seed=20241016, config=None):
     overrides = {
-        "config": {"data": {"rows": 20000}, "store": {"capacity": 512}},
+        "config": {"data": {"rows": 20000}, "store": {"capacity": 512},
+                   **(config or {})},
         "traffic": {"rate_qps": 24.0},
         "warm_sizes": (8,),
     }

@@ -60,9 +60,10 @@ def test_worst_answer_shows_the_sample_it_was_computed_on(read):
         x[slots[:3]] = rows[:3]
     spec = {"agg": "sum", "preds": [["range", "l_quantity", 0.5, 24.5]],
             "target": "l_quantity", "group_by": None}
-    (est, half, _c, _m), = ref.kde_answers(
+    plugin = harness.load_module("estimators", "plugin")
+    (est, half, _c, _m), = plugin.answers(
         [ref.box_of(spec, ("l_quantity",), None)], ["sum"], x,
-        np.asarray([ref.plugin_h(x)]), n_seen)
+        plugin.bandwidth(x, ref.F64), n_seen, ref.F64)
     kde = types.SimpleNamespace(estimate=est, ci_lo=est - half,
                                 ci_hi=est + half, group=None)
     eq = {"agg": "count", "preds": [["eq", "l_returnflag", 0.0]],
@@ -74,7 +75,7 @@ def test_worst_answer_shows_the_sample_it_was_computed_on(read):
             "kde": [(spec, kde, "l_quantity", label)],
             "exact": [(eq, exact, "l_returnflag", 3)],
             "h_prog": {}, "unanswered": 0, "stale": 0}
-    out = compare.readings(work)
+    out = compare.readings(work, plugin)
     worst = out["worst"]["estimate"]
     assert worst["version"] == label and worst["gap"] > 1e-5
     torn = worst["torn"]

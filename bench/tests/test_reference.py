@@ -1,6 +1,6 @@
 """The plain reference against the program at small sizes: the reservoir
-replay reproduces the store's samples exactly, and the PLUGIN bandwidth and
-the KDE answers agree to float32 rounding."""
+replay reproduces the store's samples exactly, and the PLUGIN estimator's
+bandwidth and KDE answers agree to float32 rounding."""
 import numpy as np
 import pytest
 
@@ -9,6 +9,7 @@ from bench import reference as ref
 from bench import traffic as traffic_mod
 
 CONFIGS = ("telemetry-4m-32k", "tpch-lineitem-sf1")
+PLUGIN = harness.load_module("estimators", "plugin")
 
 
 def _small(name, rows=6000, capacity=256):
@@ -42,9 +43,9 @@ def test_plugin_matches_program(seed):
 
     x = np.random.default_rng(seed).gamma(3.0, 0.7, 2048).astype(np.float32)
     h_prog = float(plugin_bandwidth(x).h)
-    h_ref = ref.plugin_h(x)
+    h_ref = PLUGIN.plugin_h(x)
     assert abs(h_prog - h_ref) / h_ref < 1e-4
-    h_bf16 = ref.plugin_h(x, ref.Precision("bfloat16"))
+    h_bf16 = PLUGIN.plugin_h(x, ref.Precision("bfloat16"))
     assert abs(h_bf16 - h_ref) / h_ref > 1e-4
 
 
@@ -69,10 +70,9 @@ def test_kde_answers_match_engine():
         prog = store.joints[key] if isinstance(key, tuple) \
             else store.columns[key]
         x = prog.sample()
-        x2 = x.reshape(x.shape[0], -1)
-        h = np.asarray([ref.plugin_h(x2[:, j]) for j in range(x2.shape[1])])
-        (est, half, count, m_t), = ref.kde_answers(
-            [ref.box_of(s, cols, r.group)], [s["agg"]], x, h, prog.n_seen)
+        (est, half, count, m_t), = PLUGIN.answers(
+            [ref.box_of(s, cols, r.group)], [s["agg"]], x,
+            PLUGIN.bandwidth(x, ref.F64), prog.n_seen, ref.F64)
         from bench.compare import gap
         assert gap(s["agg"], r.estimate - est, prog.n_seen, m_t, count) < 1e-5
         assert gap(s["agg"], (r.ci_hi - r.ci_lo) / 2 - half, prog.n_seen,

@@ -1,9 +1,7 @@
-"""Kernel-level ablation: the paper's eq. (60) loop algorithm vs the
-TPU-native MXU quadratic-form expansion (DESIGN.md §2, sv_precompute).
+"""Kernel-level timings: the PLUGIN pair-sum kernel against its jnp
+reference at a matched size.
 
-Interpret-mode timings measure *algorithm* cost on CPU, not TPU performance;
-the structural win (d^2 VPU passes -> 2 small matmuls) is what §Perf records.
-Also times the jnp reference paths at matched sizes for a like-for-like view.
+Interpret-mode timings measure *algorithm* cost on CPU, not TPU performance.
 """
 from __future__ import annotations
 
@@ -17,19 +15,6 @@ from .common import emit, time_call
 def run() -> dict:
     rng = np.random.default_rng(0)
     out = {}
-    n, d = 1024, 8
-    x = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
-    m0 = rng.normal(0, 1, (d, d)).astype(np.float32)
-    m = jnp.asarray(0.2 * m0 @ m0.T + np.eye(d, dtype=np.float32))
-
-    t_paper = time_call(lambda: ops.sv_matrix(x, m, algorithm="paper"), repeats=2)
-    t_mxu = time_call(lambda: ops.sv_matrix(x, m, algorithm="mxu"), repeats=2)
-    t_ref = time_call(lambda: ref.sv_matrix(x, m), repeats=2)
-    emit(f"sv_tile_paper_alg_n{n}_d{d}", t_paper)
-    emit(f"sv_tile_mxu_alg_n{n}_d{d}", t_mxu, f"{t_paper / t_mxu:.2f}x vs paper alg")
-    emit(f"sv_jnp_ref_n{n}_d{d}", t_ref)
-    out["paper_over_mxu"] = t_paper / t_mxu
-
     xg = jnp.asarray(rng.normal(0, 1, 4096).astype(np.float32))
     t_k = time_call(lambda: ops.pairwise_scaled_ksum(xg, jnp.float32(0.3), kind="k6"),
                     repeats=2)
@@ -37,6 +22,7 @@ def run() -> dict:
                      repeats=2)
     emit("pairwise_k6_tile_n4096", t_k)
     emit("pairwise_k6_ref_n4096", t_kr)
+    out["pairwise_ref_over_tile"] = t_kr / t_k
     return out
 
 

@@ -357,7 +357,7 @@ def kernels_vs_jnp(store) -> dict:
 
 def lscv_h_objective(store) -> dict:
     """LSCV_h on a 2,048-row subsample of the (loss, latency_ms) joint: the
-    Pallas (sv_precompute + lscv_grid) and jnp objectives against the
+    Pallas (streaming lscv_grid) and jnp objectives against the
     float64 host oracle at the chosen h and the grid's first point."""
     import jax.numpy as jnp
     import numpy as np

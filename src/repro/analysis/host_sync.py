@@ -49,7 +49,7 @@ DISPATCHERS = {
     "batch_query", "batch_query_boxes", "batch_query_grouped",
     "kde_eval", "aqp_batch_sums", "aqp_box_sums", "aqp_grouped_sums",
     "qmc_box_reduce", "rff_density", "lscv_grid_sums", "gh_fused_sum",
-    "sv_matrix", "pairwise_scaled_ksum",
+    "pairwise_scaled_ksum",
 }
 
 

@@ -178,15 +178,20 @@ class KDESynopsis:
     """A fitted density synopsis for one numeric column (or column set).
 
     `h` is a scalar bandwidth for 1-D synopses, and may be a (d,) diagonal
-    bandwidth vector for multi-d synopses (per-axis PLUGIN / silverman —
-    the product-kernel form of eq. 11).  `H` is the full bandwidth matrix
-    (LSCV_H); exactly one of `h`/`H` is set.
+    bandwidth vector for multi-d synopses (per-axis PLUGIN / silverman /
+    LSCV_h — the product-kernel form of eq. 11).  `H` is the full bandwidth
+    matrix (LSCV_H); exactly one of `h`/`H` is set.  An LSCV_h synopsis also
+    keeps what its bandwidth is made of, per axis: the grid minimiser
+    `grid_h` of g(h) (eq. 24, for H = h^2 sigma^2) and the sample standard
+    deviation `sigma` (eq. 22), with h = grid_h * sigma.
     """
     x: jax.Array                  # retained sample (the synopsis payload)
     h: Optional[jax.Array] = None # scalar or (d,) diagonal bandwidth
     H: Optional[jax.Array] = None # full bandwidth matrix (LSCV_H)
     n_source: int = 0             # size of the original relation
     selector: str = "plugin"
+    grid_h: Optional[jax.Array] = None  # LSCV_h grid minimiser(s)
+    sigma: Optional[jax.Array] = None   # LSCV_h sample std deviation(s)
 
     @classmethod
     def fit(cls, data: jax.Array, selector: str = "plugin", max_sample: int = 4096,
@@ -215,8 +220,18 @@ class KDESynopsis:
                                for j in range(sample.shape[1])])
             return cls(x=sample, h=h, n_source=n_source, selector=selector)
         if selector == "lscv_h":
-            res = lscv_h(sample, backend=backend)
-            return cls(x=sample, h=res.h, n_source=n_source, selector=selector)
+            # LSCV_h picks h for H = h^2 Sigma; the product kernel takes a
+            # diagonal, so each axis gets its own 1-D LSCV_h (as PLUGIN
+            # does) and bandwidth h_j sigma_j
+            axes = [sample] if sample.ndim == 1 else \
+                [sample[:, j] for j in range(sample.shape[1])]
+            fits = [lscv_h(a, backend=backend) for a in axes]
+            grid_h = jnp.stack([f.h for f in fits])
+            sigma = jnp.sqrt(jnp.stack([f.sigma[0, 0] for f in fits]))
+            if sample.ndim == 1:
+                grid_h, sigma = grid_h[0], sigma[0]
+            return cls(x=sample, h=grid_h * sigma, n_source=n_source,
+                       selector=selector, grid_h=grid_h, sigma=sigma)
         if selector == "lscv_H":
             res = lscv_H(sample if sample.ndim == 2 else sample[:, None])
             return cls(x=sample, H=res.H, n_source=n_source, selector=selector)

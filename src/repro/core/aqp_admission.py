@@ -536,6 +536,9 @@ class AqpSession:
             "max_depth": self.max_depth,
             "priorities": self.priority_counts,
             "plan_cache": self.engine.plans.stats(),
+            # bandwidth fits of every store in the process, every selector
+            # (`aqp.synopsis.fits`): the refits an insert costs
+            "fits": int(obs.get_registry().sum_counter("aqp.synopsis.fits")),
         }
 
     # -- internals -----------------------------------------------------------

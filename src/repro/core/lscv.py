@@ -8,6 +8,8 @@ LSCV_h  — scalar bandwidth h, any d: brute-force minimisation of g(h) (eq. 24)
               S: each (chunk x n) slab of quadratic forms is folded into the
               per-h partial sums for the whole grid in one pass.  Same FLOPs as
               (a), O(chunk*n) memory instead of O(n^2)        [store_s=False]
+          (c) (b) as one Pallas kernel over the upper-triangle tiles only,
+              each pair visited once, S kept in VMEM [backend="pallas"]
 LSCV_H  — full SPD bandwidth matrix: Nelder-Mead over a log-Cholesky
           parametrisation of H (guarantees SPD — the paper instead rejects
           non-SPD candidates inside NM; see DESIGN.md §2), objective g(H)
@@ -26,7 +28,7 @@ import jax.numpy as jnp
 from . import gaussian as G
 from .nelder_mead import minimize as nm_minimize
 from .reductions import (pairwise_quadform_chunks, pairwise_quadform_reduce,
-                         pairwise_sv_matrix)
+                         pairwise_sv_matrix, two_prod, two_sum)
 
 N_H_DEFAULT = 150  # paper §7.1: objective evaluated at a fixed 150 grid points
 
@@ -74,26 +76,35 @@ class LSCVhResult(NamedTuple):
     h0: jax.Array
 
 
-def _t_sums_from_S(s_matrix: jax.Array, mask: jax.Array, h_grid: jax.Array,
+def exp_scales(h_grid: jax.Array) -> jax.Array:
+    """-1 / (4 h^2) per grid point: e_4 = exp(scale S), e_2 = e_4^2 =
+    exp(2 scale S).  Every grid-sum path takes these, and g's 1 / h^d is
+    taken from the same values (`_inv_h`)."""
+    return -0.25 / (h_grid * h_grid)
+
+
+def _t_sums_from_S(s_matrix: jax.Array, mask: jax.Array, scales: jax.Array,
                    c_k: jax.Array, c_kk: jax.Array, h_chunk: int = 8) -> jax.Array:
     """Paper-faithful phase 2 (§6.2): for each h on the grid, reduce
-    T~(v) = (K~*K~)(v) - 2 K~(v) over the precomputed S values (eqs. 40-42)."""
-    def per_h(h):
-        e2 = jnp.exp(-0.5 * s_matrix / (h * h))
-        e4 = jnp.exp(-0.25 * s_matrix / (h * h))
+    T~(v) = (K~*K~)(v) - 2 K~(v) over the precomputed S values (eqs. 40-42).
+    `scales`: `exp_scales(h_grid)`."""
+    def per_h(c):
+        e2 = jnp.exp(2.0 * c * s_matrix)
+        e4 = jnp.exp(c * s_matrix)
         return jnp.sum(jnp.where(mask, c_kk * e4 - 2.0 * c_k * e2, 0.0))
 
-    return jax.lax.map(per_h, h_grid, batch_size=h_chunk)
+    return jax.lax.map(per_h, scales, batch_size=h_chunk)
 
 
-def _t_sums_streaming(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
+def _t_sums_streaming(x: jax.Array, sigma_inv: jax.Array, scales: jax.Array,
                       c_k: jax.Array, c_kk: jax.Array, chunk: int = 128,
                       h_chunk: int = 8) -> jax.Array:
     """Beyond-paper fused grid: one pass over quadratic-form slabs accumulates
-    sum_{i<j} T~ for every h simultaneously.  Memory O(chunk * n * h_chunk)."""
+    sum_{i<j} T~ for every h simultaneously.  Memory O(chunk * n * h_chunk).
+    `scales`: `exp_scales(h_grid)`."""
     scan_slabs = pairwise_quadform_chunks(x, sigma_inv, chunk)
-    inv2 = 0.5 / (h_grid * h_grid)   # (n_h,)
-    inv4 = 0.25 / (h_grid * h_grid)
+    inv2 = -2.0 * scales   # 1 / (2 h^2), (n_h,)
+    inv4 = -scales         # 1 / (4 h^2)
 
     def consume(acc, s, mask):
         sm = jnp.where(mask, s, 0.0)
@@ -105,14 +116,14 @@ def _t_sums_streaming(x: jax.Array, sigma_inv: jax.Array, h_grid: jax.Array,
             e4 = jnp.exp(-sm[None, :, :] * i4[:, None, None])
             return jnp.sum((c_kk * e4 - 2.0 * c_k * e2) * w[None, :, :], axis=(1, 2))
 
-        n_h = h_grid.shape[0]
+        n_h = scales.shape[0]
         pad = (-n_h) % h_chunk
         i2 = jnp.pad(inv2, (0, pad)).reshape(-1, h_chunk)
         i4 = jnp.pad(inv4, (0, pad)).reshape(-1, h_chunk)
         contrib = jax.lax.map(per_h_chunk, (i2, i4)).reshape(-1)[:n_h]
         return acc + contrib
 
-    return scan_slabs(consume, jnp.zeros((h_grid.shape[0],), x.dtype))
+    return scan_slabs(consume, jnp.zeros((scales.shape[0],), x.dtype))
 
 
 @partial(jax.jit, static_argnames=("n_h", "store_s", "chunk", "backend"))
@@ -133,23 +144,56 @@ def lscv_h(x: jax.Array, n_h: int = N_H_DEFAULT, store_s: bool = False,
     h_grid = h_grid_for(n, d, n_h).astype(x.dtype)
 
     c_k, c_kk, r_k = G.lscv_h_consts(d, det_sigma)
+    scales = exp_scales(h_grid)
 
     # Steps 6-7: S(v) precompute + grid search (paper), or fused streaming.
+    t_lo = jnp.zeros_like(h_grid)
     if backend == "pallas":
         from repro.kernels import ops as kops
-        t_sums = kops.lscv_grid_sums(x, sigma_inv, h_grid, c_k, c_kk)
+        t_sums, t_lo = kops.lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk)
     elif store_s:
         s_matrix = pairwise_sv_matrix(x, sigma_inv, chunk)
         rows = jnp.arange(n)
         mask = rows[:, None] < rows[None, :]
-        t_sums = _t_sums_from_S(s_matrix, mask, h_grid, c_k, c_kk)
+        t_sums = _t_sums_from_S(s_matrix, mask, scales, c_k, c_kk)
     else:
-        t_sums = _t_sums_streaming(x, sigma_inv, h_grid, c_k, c_kk, chunk)
+        t_sums = _t_sums_streaming(x, sigma_inv, scales, c_k, c_kk, chunk)
 
-    g_values = h_grid ** (-d) * (2.0 / (n * n) * t_sums + r_k / n)   # eq. (43)
-    best = jnp.argmin(g_values)
+    inv_hi, inv_lo = _inv_h(scales)
+    g_values = (inv_hi + inv_lo) ** d * (2.0 / (n * n) * (t_sums + t_lo)
+                                         + r_k / n)               # eq. (43)
+    best = _argmin_g(t_sums, t_lo, inv_hi, inv_lo, r_k * (n / 2.0), d)
     return LSCVhResult(h=h_grid[best], h_grid=h_grid, g_values=g_values,
                        sigma=sigma, det_sigma=det_sigma, h0=h0)
+
+
+def _inv_h(scales):
+    """1 / h = 2 sqrt(-scale) as a float32 pair: the h whose exponent the
+    sums used.  h's float32 scale carries its own rounding, different at
+    each grid point; g's 1 / h^d taken from h itself would move g by ~1e-7
+    from one grid point to the next, more than g's two best points can
+    differ by."""
+    a = -scales
+    s = jnp.sqrt(a)
+    p, p_err = two_prod(s, s)
+    return two_sum(2.0 * s, ((a - p) - p_err) / s)
+
+
+def _argmin_g(t_hi, t_lo, inv_hi, inv_lo, k, d: int):
+    """argmin of eq. 43's g over the grid, from the pair sums as float32
+    pairs (t_hi + t_lo): g = (2 / n^2) (t + k) / h^d with k = r_k n / 2,
+    and the positive factor 2 / n^2 left out; 1 / h as the pair
+    (inv_hi + inv_lo).  Near its minimum g's grid neighbours can differ by
+    less than one float32 rounding, so every step carries its error
+    (`reductions.two_sum` / `two_prod`) and the candidates are compared by
+    their difference from the float32 minimiser."""
+    hi, lo = two_sum(t_hi, k)
+    lo = lo + t_lo
+    for _ in range(d):                     # (hi + lo) (inv_hi + inv_lo)
+        p, p_err = two_prod(hi, inv_hi)
+        hi, lo = two_sum(p, p_err + hi * inv_lo + lo * inv_hi)
+    b = jnp.argmin(hi + lo)
+    return jnp.argmin((hi - hi[b]) + (lo - lo[b]))
 
 
 def g_of_h_sequential(x, h) -> float:

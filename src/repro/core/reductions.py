@@ -45,6 +45,44 @@ def kahan_sum(a: jax.Array) -> jax.Array:
     return s
 
 
+# --- float32 pairs (hi, lo): a value and its rounding error, carried apart so
+# that sums of ~n^2 terms keep more digits than one float32 (LSCV_h argmin) ---
+
+def two_sum(a, b):
+    """a + b as (rounded sum, its exact rounding error)."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def _split(a):
+    """Veltkamp split of a float32 into two halves of 12 bits or fewer."""
+    c = 4097.0 * a
+    hi = c - (c - a)
+    return hi, a - hi
+
+
+def two_prod(a, b):
+    """a * b as (rounded product, its exact rounding error), no FMA needed."""
+    p = a * b
+    ah, al = _split(a)
+    bh, bl = _split(b)
+    return p, ((ah * bh - p) + ah * bl + al * bh) + al * bl
+
+
+def pair_sum(p: jax.Array):
+    """Row sums of p (rows, m) as float32 pairs (hi, lo): added pairwise,
+    every rounding error carried."""
+    hi, lo = p, jnp.zeros_like(p)
+    while hi.shape[1] > 1:
+        if hi.shape[1] % 2:
+            hi = jnp.pad(hi, ((0, 0), (0, 1)))
+            lo = jnp.pad(lo, ((0, 0), (0, 1)))
+        hi, err = two_sum(hi[:, 0::2], hi[:, 1::2])
+        lo = lo[:, 0::2] + lo[:, 1::2] + err
+    return two_sum(hi[:, 0], lo[:, 0])
+
+
 def map_reduce(fun: Callable, a: jax.Array, chunk: int = 65536) -> jax.Array:
     """R_fun(A) (§5.3): sum_i fun(A_i), computed on-the-fly without storing fun(A).
 

@@ -49,10 +49,17 @@ import numpy as np
 from repro import obs
 from repro.core.aqp import KDESynopsis, Query, canonical_selector
 from repro.core.aqp_multid import BoxQuery
+from repro.core.lscv import N_H_DEFAULT
 
 ColumnKey = Union[str, Tuple[str, ...]]
 
 STATE_FORMAT = 1     # bump on incompatible to_state layout changes
+
+# Bandwidth fits (`_fit_cached`) count in the process-global registry, as the
+# kernels' counters do, so that a reader with no store handle (a benchmark's
+# per-layer reader) finds them.  Declared here, at zero and unlabelled, so
+# that the counter is there before the process's first fit.
+obs.get_registry().counter("aqp.synopsis.fits")
 
 
 class Reservoir:
@@ -896,6 +903,12 @@ class SynopsisCache:
             return self._bytes
 
     def get(self, column: ColumnKey, selector: str, version: int) -> Optional[KDESynopsis]:
+        return self.lookup(column, selector, version)[0]
+
+    def lookup(self, column: ColumnKey, selector: str,
+               version: int) -> Tuple[Optional[KDESynopsis], bool]:
+        """`get`, and on a miss whether the entry was held at an older
+        version (an insert left it stale): (synopsis or None, stale)."""
         # selector case-normalized: "Plugin" and "plugin" are the same
         # synopsis and must share one entry, not collide as two live copies
         key = (column, canonical_selector(selector))
@@ -906,11 +919,11 @@ class SynopsisCache:
                 if self._m_hits is not None:
                     self._m_hits.inc()
                 self._entries.move_to_end(key)        # LRU: refresh recency
-                return ent[1]
+                return ent[1], False
             self.misses += 1
             if self._m_misses is not None:
                 self._m_misses.inc()
-            return None
+            return None, ent is not None and ent[0] < version
 
     def put(self, column: ColumnKey, selector: str, version: int, syn: KDESynopsis) -> None:
         key = (column, canonical_selector(selector))
@@ -1237,11 +1250,11 @@ class TelemetryStore:
                        tier: Optional[int] = None,
                        backend: str = "jnp") -> KDESynopsis:
         """Joint synopsis over a tracked column tuple: per-axis diagonal
-        bandwidths (plugin/silverman), scalar LSCV_h, or full-H LSCV_H.
+        bandwidths (plugin/silverman/LSCV_h), or full-H LSCV_H.
 
-        `backend` ("jnp" | "pallas") runs the PLUGIN pair sums; the other
-        selectors fit as they always have.  A cached synopsis serves either
-        backend: both compute the same estimator to float32 rounding."""
+        `backend` ("jnp" | "pallas") runs the PLUGIN and LSCV_h pair sums;
+        LSCV_H fits on jnp.  A cached synopsis serves either backend: both
+        compute the same estimator to float32 rounding."""
         key = tuple(columns)
         res = self.joints.get(key)
         if res is None:
@@ -1260,19 +1273,25 @@ class TelemetryStore:
         selector = canonical_selector(selector)
         tier = _effective_tier(res, tier)
         ckey = _tier_key(key, tier)
-        syn = self.cache.get(ckey, selector, res.version)
+        syn, stale = self.cache.lookup(ckey, selector, res.version)
         if syn is None:
             data = res.sample() if tier is None else res.sample(tier)
-            d = data.shape[1] if data.ndim > 1 else 1
-            # only PLUGIN follows the caller's backend: the Pallas LSCV_h
-            # fit holds an n x n matrix in HBM
-            backend = backend if selector == "plugin" else "jnp"
-            with obs.span("synopsis.fit", n=data.shape[0], d=d,
-                          selector=selector, backend=backend,
-                          pallas=int(backend == "pallas")):
+            n, d = data.shape[0], (data.shape[1] if data.ndim > 1 else 1)
+            # PLUGIN and LSCV_h run their pair sums on the caller's backend;
+            # LSCV_H keeps its jnp Nelder-Mead
+            backend = backend if selector in ("plugin", "lscv_h") else "jnp"
+            grid = {}
+            if selector == "lscv_h":        # d one-axis grid searches
+                grid = {"n_h": N_H_DEFAULT,
+                        "pair_terms": d * n * (n - 1) // 2 * N_H_DEFAULT}
+            with obs.span("synopsis.fit", n=n, d=d, selector=selector,
+                          backend=backend, pallas=int(backend == "pallas"),
+                          **grid):
                 syn = KDESynopsis.fit(data, selector=selector,
                                       max_sample=self.capacity,
                                       backend=backend)
+            obs.get_registry().counter("aqp.synopsis.fits", selector=selector,
+                                       stale=int(stale)).inc()
             # scale against the FULL stream: every tier is a uniform sample
             # of it, so tier answers are unbiased for the same relation
             syn.n_source = res.n_seen

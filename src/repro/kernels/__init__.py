@@ -1,8 +1,7 @@
 """Pallas TPU kernels for the paper's compute hot spots (§5).
 
 pairwise_reduce — RR_fun triangular reduction (PLUGIN psi sums)      [§5.4]
-sv_precompute   — S(v) quadratic-form tiles (LSCV_h precompute)      [§5.5/§4.5]
-lscv_grid       — per-h T~ reduction over precomputed S (LSCV_h)     [§6.2]
+lscv_grid       — streaming triangular LSCV_h grid sums (all h)     [§4.5/§6.2]
 gh_fused        — fused quadratic-form + T_H reduction (LSCV_H)      [§6.3]
 kde_eval        — direct KDE evaluation (AQP serving)                [eq. 3]
 aqp_batch       — batched (queries x sample) Phi-diff reduction      [eqs. 9-10]

@@ -10,7 +10,7 @@ scalar partial out.
     T_H(s) = c_kk * exp(-s/4) - 2 * c_k * exp(-s/2)        (eqs. 33-35)
 
 Triangle-only 1-D grid via Appendix-A index math, MXU quadratic-form
-expansion as in sv_precompute.
+expansion S = qe + qf - 2 e M f^T (as in lscv_grid).
 """
 from __future__ import annotations
 

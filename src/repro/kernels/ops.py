@@ -33,7 +33,6 @@ from . import lscv_grid as _lg
 from . import pairwise_reduce as _pr
 from . import qmc_reduce as _qmc
 from . import rff_eval as _rff
-from . import sv_precompute as _sv
 from .tuning import interpret, profiled_call
 
 
@@ -58,18 +57,6 @@ def pairwise_scaled_ksum(x, g, kind="k4", tile=None):
         n=x.shape[0], kind=kind, tile=tile)
 
 
-def sv_matrix(x, m, tile=None, algorithm="mxu"):
-    d = x.shape[1] if x.ndim > 1 else 1
-    (tile,) = _tune.resolve(
-        "sv_matrix", {"n": x.shape[0], "d": d},
-        tile=(tile, "REPRO_SV_TILE", _sv.TILE))
-    return _dispatch(
-        "sv_matrix",
-        lambda interp: _sv.sv_matrix(x, m, tile=tile, algorithm=algorithm,
-                                     interpret=interp),
-        n=x.shape[0], d=d, tile=tile, algorithm=algorithm)
-
-
 def gh_fused_sum(x, h_inv, c_k, c_kk, tile=None):
     d = x.shape[1] if x.ndim > 1 else 1
     (tile,) = _tune.resolve(
@@ -82,17 +69,15 @@ def gh_fused_sum(x, h_inv, c_k, c_kk, tile=None):
         n=x.shape[0], d=d, tile=tile)
 
 
-def lscv_grid_sums(x, sigma_inv, h_grid, c_k, c_kk, tile=None, h_tile=None):
-    tile, h_tile = _tune.resolve(
-        "lscv_grid_sums", {"n": x.shape[0], "G": h_grid.shape[0]},
-        tile=(tile, "REPRO_LSCV_TILE", _lg.TILE),
-        h_tile=(h_tile, "REPRO_LSCV_H_TILE", _lg.H_TILE))
+def lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk, tile=None):
+    (tile,) = _tune.resolve(
+        "lscv_grid_sums", {"n": x.shape[0], "G": scales.shape[0]},
+        tile=(tile, "REPRO_LSCV_TILE", _lg.TILE))
     return _dispatch(
         "lscv_grid_sums",
-        lambda interp: _lg.lscv_grid_sums(x, sigma_inv, h_grid, c_k, c_kk,
-                                          tile=tile, h_tile=h_tile,
-                                          interpret=interp),
-        n=x.shape[0], G=h_grid.shape[0], tile=tile, h_tile=h_tile)
+        lambda interp: _lg.lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk,
+                                          tile=tile, interpret=interp),
+        n=x.shape[0], G=scales.shape[0], tile=tile)
 
 
 def kde_eval(points, x, h, tile=None):

@@ -37,7 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import gaussian as G
 from .triangle import bx_to_ql, n_tri_tiles
-from .tuning import resolve_tile
+from .tuning import resolve_tile, square_tile
 
 TILE = 4096
 _UNROLL = 32                    # columns per loop trip
@@ -121,15 +121,6 @@ def _kernel(c_ref, col_ref, row_ref, out_ref, *, kind: str, n: int, k: int):
         out_ref[...] = band(0, k, n_rows)
 
 
-def _tile(tile: int, n: int) -> int:
-    """Square tile side: the least power of two from 128 (the lane width)
-    that reaches `tile` or covers the n points."""
-    k = _LANES
-    while k < tile and k < n:
-        k *= 2
-    return k
-
-
 def pairwise_scaled_ksum(x: jax.Array, g: jax.Array, kind: str = "k4",
                          tile=None, interpret: bool = True) -> jax.Array:
     """sum_{i<j} fun((x_i - x_j)/g) for 1-D x via the triangular tile kernel.
@@ -144,7 +135,7 @@ def pairwise_scaled_ksum(x: jax.Array, g: jax.Array, kind: str = "k4",
 def _pairwise_scaled_ksum(x: jax.Array, g: jax.Array, kind: str,
                           tile: int, interpret: bool) -> jax.Array:
     n = x.shape[0]
-    k = _tile(tile, n)
+    k = square_tile(tile, n)
     xp = jnp.pad(x.astype(jnp.float32), (0, (-n) % k))
     n_tiles = xp.shape[0] // k
     grid = (n_tri_tiles(n_tiles),)

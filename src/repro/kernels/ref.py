@@ -41,18 +41,19 @@ def gh_fused_sum(x: jnp.ndarray, h_inv: jnp.ndarray, c_k, c_kk) -> jnp.ndarray:
     return jnp.sum(jnp.where(_pair_mask(x.shape[0]), t, 0.0))
 
 
-def lscv_grid_sums(x: jnp.ndarray, sigma_inv: jnp.ndarray, h_grid: jnp.ndarray,
+def lscv_grid_sums(x: jnp.ndarray, sigma_inv: jnp.ndarray, scales: jnp.ndarray,
                    c_k, c_kk) -> jnp.ndarray:
-    """Per-h inner sums of eq. (43): for each h, sum_{i<j} T~(x_i - x_j)."""
+    """Per-h inner sums of eq. (43): for each h, sum_{i<j} T~(x_i - x_j);
+    the grid given as its exponent scales -1 / (4 h^2)."""
     s = sv_matrix(x, sigma_inv)
     mask = _pair_mask(x.shape[0])
 
-    def per_h(h):
-        t = c_kk * jnp.exp(-0.25 * s / (h * h)) - 2.0 * c_k * jnp.exp(-0.5 * s / (h * h))
+    def per_h(c):
+        t = c_kk * jnp.exp(c * s) - 2.0 * c_k * jnp.exp(2.0 * c * s)
         return jnp.sum(jnp.where(mask, t, 0.0))
 
     import jax
-    return jax.vmap(per_h)(h_grid)
+    return jax.vmap(per_h)(scales)
 
 
 def kde_eval(points: jnp.ndarray, x: jnp.ndarray, h) -> jnp.ndarray:

@@ -77,6 +77,16 @@ def lane_tile(tile: int, n: int) -> int:
     return full if full <= tile else -(-tile // 128) * 128
 
 
+def square_tile(tile: int, n: int) -> int:
+    """Side of a square tile of the implicit n x n pair matrix (the
+    triangular pair kernels): the least power of two from 128, the lane
+    width, that reaches `tile` or covers the n points."""
+    k = 128
+    while k < tile and k < n:
+        k *= 2
+    return k
+
+
 def profiled_call(kernel: str, fn, /, *args, **labels):
     """Run `fn(*args)` recording per-shape timings into the global registry.
 

@@ -181,16 +181,12 @@ register("REPRO_RFF_P_TILE", "int", 128,
 register("REPRO_PAIRWISE_TILE", "int", 4096,
          "Square tile side of the pairwise_scaled_ksum Pallas kernel "
          "(PLUGIN selector inner sums), rounded up to a power of two.")
-register("REPRO_SV_TILE", "int", 256,
-         "Data-tile size of the sv_matrix Pallas kernel (LSCV_H "
-         "precompute).")
 register("REPRO_GH_TILE", "int", 256,
          "Data-tile size of the gh_fused_sum Pallas kernel (fused LSCV_H "
          "objective).")
 register("REPRO_KDE_EVAL_TILE", "int", 256,
          "Data-tile size of the kde_eval Pallas kernel (grid KDE "
          "evaluation).")
-register("REPRO_LSCV_TILE", "int", 256,
-         "Data-tile size of the lscv_grid_sums Pallas kernel.")
-register("REPRO_LSCV_H_TILE", "int", 8,
-         "Bandwidth-grid tile size of the lscv_grid_sums Pallas kernel.")
+register("REPRO_LSCV_TILE", "int", 1024,
+         "Square tile side of the lscv_grid_sums Pallas kernel (LSCV_h "
+         "grid sums), rounded up to a power of two.")

@@ -549,17 +549,129 @@ def test_plugin_refit_follows_engine_backend(rng, monkeypatch, fit_spans,
     assert np.isfinite(res.estimate)
 
 
-def test_lscv_fit_ignores_engine_backend(rng, fit_spans):
-    """LSCV_h keeps its jnp fit whatever the engine's backend: the same
-    data gives the same bandwidth under either engine."""
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_lscv_fit_follows_engine_backend(rng, monkeypatch, fit_spans,
+                                         backend):
+    """An LSCV_h fit runs its grid sums on the Pallas kernel exactly when the
+    engine's backend is pallas, and either backend picks the same grid
+    point: the bandwidth is the jnp LSCV_h minimiser times the sample's
+    standard deviation."""
+    from repro.core.lscv import lscv_h
+    from repro.kernels import ops as kops
+    calls = []
+    real = kops.lscv_grid_sums
+
+    def spy(*args, **kwargs):
+        calls.append(args[0].shape)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(kops, "lscv_grid_sums", spy)
+    lscv_h.clear_cache()                # the spy is seen when the fit traces
+    store, *_ = _store(np.random.default_rng(3), n=4000, capacity=256)
     spec = AqpQuery("count", (Range("a", -1.0, 1.0),), selector="lscv_h")
-    hs = []
-    for backend in ("jnp", "pallas"):
-        store, *_ = _store(np.random.default_rng(3), n=4000, capacity=256)
-        store.engine(backend=backend).execute(spec)
-        hs.append(float(store.synopsis("a", "lscv_h").h))
-    assert hs[0] == hs[1]
-    assert [b for sel, b in fit_spans()] == ["jnp", "jnp"]
+    store.engine(backend=backend).execute(spec)
+    assert fit_spans() == [("lscv_h", backend)]
+    assert calls == ([(256, 1)] if backend == "pallas" else [])
+    syn = store.synopsis("a", "lscv_h")
+    sample = store.columns["a"].sample()
+    want = lscv_h(jnp.asarray(sample))
+    assert float(syn.grid_h) == float(want.h)
+    assert float(syn.sigma) == pytest.approx(float(np.std(sample, ddof=1)),
+                                             rel=1e-5)
+    assert float(syn.h) == pytest.approx(float(want.h) * float(syn.sigma),
+                                         rel=1e-6)
+
+
+def _lscv_h64(x: np.ndarray) -> float:
+    """Float64 LSCV_h bandwidth h sigma of a 1-D sample: g(h) (eq. 24 in
+    the eq. 43 form) on eq. 29's 150-point grid, minimised."""
+    x = np.asarray(x, np.float64)
+    n = x.size
+    sigma = np.std(x, ddof=1)
+    h0 = (4.0 / 3.0) ** 0.2 * n ** -0.2            # eq. 28 at d = 1
+    grid = np.linspace(h0 / 4.0, 4.0 * h0, 150)
+    i, j = np.triu_indices(n, 1)
+    s = ((x[i] - x[j]) / sigma) ** 2
+    c_k = (2.0 * np.pi) ** -0.5 / sigma
+    c_kk = (4.0 * np.pi) ** -0.5 / sigma
+    g = [(2.0 / n ** 2 * np.sum(c_kk * np.exp(-s / (4 * h * h))
+                                - 2.0 * c_k * np.exp(-s / (2 * h * h)))
+          + c_kk / n) / h for h in grid]
+    return float(grid[int(np.argmin(g))] * sigma)
+
+
+def _box_count64(x: np.ndarray, h: np.ndarray, lo, hi, scale: float):
+    """Float64 COUNT of the Gaussian product KDE of x (m, d) over a box."""
+    from scipy.special import ndtr
+    x = np.asarray(x, np.float64).reshape(x.shape[0], -1)
+    mass = np.prod(ndtr((np.asarray(hi) - x) / h)
+                   - ndtr((np.asarray(lo) - x) / h), axis=1)
+    return scale * float(np.sum(mass))
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_lscv_h_answers_integrate_per_axis_bandwidths(backend):
+    """Served lscv_h range, box and GROUP BY answers equal the float64
+    product-kernel integral at bandwidth h_j sigma_j per axis, h_j each
+    axis's own LSCV_h grid minimiser (on columns whose sigma is far from
+    1, where the bare grid value would be another KDE)."""
+    rng = np.random.default_rng(11)
+    n = 6000
+    a = rng.normal(40.0, 8.0, n).astype(np.float32)
+    b = (0.05 * a + rng.normal(0.0, 0.3, n)).astype(np.float32)
+    code = rng.integers(0, 4, n).astype(np.float32)
+    store = TelemetryStore(capacity=256, seed=0)
+    store.track_joint(("a", "b"))
+    store.track_joint(("code", "a"))
+    store.add_batch({"a": a, "b": b, "code": code})
+    eng = store.engine(selector="lscv_h", backend=backend)
+    rng_q = AqpQuery("count", (Range("a", 30.0, 45.0),))
+    box_q = AqpQuery("count", (Box(("a", "b"), (30.0, 1.5), (45.0, 2.5)),))
+    grp_q = AqpQuery("count", (Range("a", 30.0, 45.0),), group_by="code")
+    got_range, got_box = (r.estimate for r in eng.execute([rng_q, box_q]))
+    got_groups = eng.execute(grp_q)
+
+    def want(key, lo, hi):
+        res = store.joints[key] if isinstance(key, tuple) \
+            else store.columns[key]
+        x = res.sample().reshape(res.sample().shape[0], -1)
+        h = np.asarray([_lscv_h64(x[:, j]) for j in range(x.shape[1])])
+        return _box_count64(x, h, lo, hi, res.n_seen / x.shape[0])
+
+    assert got_range == pytest.approx(want("a", [30.0], [45.0]), rel=1e-4)
+    assert got_box == pytest.approx(
+        want(("a", "b"), [30.0, 1.5], [45.0, 2.5]), rel=1e-4)
+    assert [r.group for r in got_groups] == [0.0, 1.0, 2.0, 3.0]
+    for r in got_groups:
+        assert r.estimate == pytest.approx(
+            want(("code", "a"), [r.group - 0.5, 30.0], [r.group + 0.5, 45.0]),
+            rel=1e-4)
+
+
+def test_fit_counters_count_refits_an_insert_forces(rng):
+    """Every fit of `_fit_cached` counts in the process-wide
+    `aqp.synopsis.fits` (the session's `stats()["fits"]`); one that replaces
+    a synopsis an insert left stale carries `stale=1` (a cache hit counts
+    nowhere)."""
+    from repro import obs
+    store, a, b, code = _store(rng, n=4000, capacity=256)
+    reg = obs.get_registry()
+    spec = AqpQuery("count", (Range("a", -1.0, 1.0),))
+    with store.session(watermark=1) as session:
+        fits0 = session.stats()["fits"]
+        refits0 = reg.sum_counter("aqp.synopsis.fits", stale=1)
+
+        def counts():
+            return (session.stats()["fits"] - fits0,
+                    reg.sum_counter("aqp.synopsis.fits", stale=1) - refits0)
+
+        session.submit(spec).result()
+        session.submit(spec).result()               # a cache hit
+        assert counts() == (1, 0)
+        store.add_batch({"a": a[:500], "b": b[:500], "code": code[:500]})
+        store.synopsis("b")                         # b's first fit
+        session.submit(spec).result()               # a's refit
+        assert counts() == (3, 1)
 
 
 # --- batched QMC fallback ----------------------------------------------------

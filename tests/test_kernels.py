@@ -50,17 +50,6 @@ def test_pairwise_ksum(n, tile, kind):
                                atol=max(1e-5, 1e-6 * n))
 
 
-@pytest.mark.parametrize("n,d", [(9, 1), (64, 2), (130, 5), (300, 16)])
-@pytest.mark.parametrize("alg", ["paper", "mxu"])
-def test_sv_matrix(rng, n, d, alg):
-    x = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
-    m0 = rng.normal(0, 1, (d, d)).astype(np.float32)
-    m = jnp.asarray(0.2 * (m0 @ m0.T) + np.eye(d, dtype=np.float32))
-    a = ops.sv_matrix(x, m, tile=64, algorithm=alg)
-    b = ref.sv_matrix(x, m)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
-
-
 @pytest.mark.parametrize("n,d", [(40, 2), (222, 4), (513, 8)])
 def test_gh_fused(rng, n, d):
     x = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
@@ -71,15 +60,25 @@ def test_gh_fused(rng, n, d):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("n,d,n_h", [(100, 2, 5), (257, 3, 13)])
+@pytest.mark.parametrize("n,d,n_h", [
+    (100, 2, 5), (257, 3, 13),
+    (100, 1, 5), (100, 3, 5), (256, 1, 13), (256, 2, 13), (256, 3, 13),
+    (300, 1, 13), (300, 2, 13)])
 def test_lscv_grid(rng, n, d, n_h):
+    """The streaming triangular kernel (128-point tiles: n = 100, 257 and
+    300 ragged, 256 two whole tiles) against the dense oracle and the jnp
+    streaming grid."""
+    from repro.core.lscv import _t_sums_streaming, exp_scales
     x = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
     m0 = rng.normal(0, 1, (d, d)).astype(np.float32)
     m = jnp.asarray(0.1 * (m0 @ m0.T) + np.eye(d, dtype=np.float32))
-    hg = jnp.linspace(0.3, 2.0, n_h).astype(jnp.float32)
-    a = ops.lscv_grid_sums(x, m, hg, 0.3, 0.2, tile=64, h_tile=4)
-    b = ref.lscv_grid_sums(x, m, hg, 0.3, 0.2)
+    scales = exp_scales(jnp.linspace(0.3, 2.0, n_h).astype(jnp.float32))
+    hi, lo = ops.lscv_grid_sums(x, m, scales, 0.3, 0.2, tile=128)
+    a = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+    b = ref.lscv_grid_sums(x, m, scales, 0.3, 0.2)
+    c = _t_sums_streaming(x, m, scales, 0.3, 0.2, chunk=64)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-3, atol=1e-3)
 
 
 @pytest.mark.parametrize("m,n,d", [(3, 17, 1), (65, 64, 2), (128, 500, 8)])

@@ -30,6 +30,37 @@ def test_store_s_fused_pallas_agree(rng):
     assert float(a.h) == float(b.h) == float(c.h)
 
 
+def test_pallas_argmin_is_float64s_on_a_near_tie():
+    """Loss-like data whose two best grid points differ by 5.7e-8 of g: the
+    float32 g values pick the wrong one; the Pallas fit, which takes the
+    argmin on its float32 pairs, picks float64's."""
+    x = np.random.default_rng(120).gamma(3.0, 0.7, 1024).astype(np.float32)
+    res = lscv_h(jnp.asarray(x), backend="pallas")
+    xd = x.astype(np.float64)
+    n, sigma = xd.size, np.std(xd, ddof=1)
+    i, j = np.triu_indices(n, 1)
+    s = ((xd[i] - xd[j]) / sigma) ** 2
+    grid = np.asarray(res.h_grid, np.float64)
+    c_k, c_kk = (2 * np.pi) ** -0.5 / sigma, (4 * np.pi) ** -0.5 / sigma
+    g = np.asarray([(2.0 / n ** 2 * np.sum(c_kk * np.exp(-s / (4 * h * h))
+                                           - 2 * c_k * np.exp(-s / (2 * h * h)))
+                     + c_kk / n) / h for h in grid])
+    assert np.argmin(np.asarray(res.g_values)) != np.argmin(g)
+    assert float(res.h) == float(res.h_grid[np.argmin(g)])
+
+
+def test_inv_h_is_the_exponent_scales_bandwidth():
+    """g's 1 / h is 2 sqrt(-scale) of the float32 scale the sums used, to
+    far below a float32 rounding: the pair off float64 by < 1e-13."""
+    from repro.core.lscv import _inv_h, exp_scales, h_grid_for
+    scales = exp_scales(h_grid_for(32768, 1))
+    hi, lo = _inv_h(scales)
+    got = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+    want = 2.0 * np.sqrt(-np.asarray(scales, np.float64))
+    assert np.max(np.abs(got / want - 1.0)) < 1e-13
+    assert np.max(np.abs(np.asarray(hi, np.float64) / want - 1.0)) > 1e-9
+
+
 def test_h0_1d_is_silverman():
     # eq. (28) for d=1 must reduce to (4/3)^(1/5) n^(-1/5)
     n = 1000

@@ -1,10 +1,11 @@
-"""All ten Pallas kernels compile for a TPU v5e, without a chip.
+"""All nine Pallas kernels compile for a TPU v5e, without a chip.
 
 Each kernel is lowered with `interpret=False` for one chip of a described
 `v5e:2x2` topology at the shapes the serving path produces (32,768-row
 reservoirs, d=2, batches padded pow2-then-x64 below and above one query
-tile, 4,096 QMC nodes, 2,048 RFF features, the 150-point LSCV_h grid) and
-compiled by the installed TPU compiler; the compiled program must contain
+tile, 4,096 QMC nodes, 2,048 RFF features, the 150-point LSCV_h grid, the
+served 1-D LSCV_h fit at 32,768 rows) and compiled by the installed TPU
+compiler; the compiled program must contain
 the Mosaic kernel (`tpu_custom_call`) under the kernel's stable name (the
 `name=` of its `pallas_call`, which a profiler trace shows as the
 operation's name).  Interpret-mode tests cannot see
@@ -24,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import (aqp_batch, aqp_boxes, aqp_grouped, gh_fused,
                            kde_eval, lscv_grid, pairwise_reduce, qmc_reduce,
-                           rff_eval, sv_precompute)
+                           rff_eval)
 
 N, D = 32_768, 2          # reservoir rows, joint dimensions
 M_QMC, FEATURES = 4_096, 2_048
@@ -96,19 +97,19 @@ def _cases():
          lambda x, g: pairwise_reduce.pairwise_scaled_ksum(
              x, g, kind="k6", interpret=False),
          [((N,), F32), ((), F32)]),
-        ("sv_precompute",
-         lambda x, m: sv_precompute.sv_matrix(x, m, interpret=False),
-         [((SUBSAMPLE, D), F32), ((D, D), F32)]),
         ("gh_fused",
          lambda x, m, ck, ckk: gh_fused.gh_fused_sum(x, m, ck, ckk,
                                                      interpret=False),
          [((N, D), F32), ((D, D), F32), ((), F32), ((), F32)]),
-        ("lscv_grid",
-         lambda x, si, hg, ck, ckk: lscv_grid.lscv_grid_sums(
-             x, si, hg, ck, ckk, interpret=False),
-         [((SUBSAMPLE, D), F32), ((D, D), F32), ((N_H,), F32), ((), F32),
-          ((), F32)]),
     ]
+    # the library's d > 1 fit, and the served 1-D fit at the reservoir's size
+    for name, rows, d in (("lscv_grid", SUBSAMPLE, D), ("lscv_grid-d1", N, 1)):
+        cases.append(
+            (name,
+             lambda x, si, sc, ck, ckk: lscv_grid.lscv_grid_sums(
+                 x, si, sc, ck, ckk, interpret=False),
+             [((rows, d), F32), ((d, d), F32), ((N_H,), F32), ((), F32),
+              ((), F32)]))
     return cases
 
 
@@ -119,7 +120,7 @@ KERNEL_NAMES = {
     "aqp_batch": "_aqp_batch_sums", "aqp_boxes": "_aqp_box_sums",
     "qmc_reduce": "_qmc_box_reduce", "aqp_grouped": "_aqp_grouped_sums",
     "rff_eval": "_rff_density", "kde_eval": "_kde_eval",
-    "pairwise_reduce": "_pairwise_scaled_ksum", "sv_precompute": "_sv_matrix",
+    "pairwise_reduce": "_pairwise_scaled_ksum",
     "gh_fused": "_gh_fused_sum", "lscv_grid": "_lscv_grid_sums",
 }
 

@@ -23,14 +23,14 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Dict, List, Mapping, Optional, Sequence, Union
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .kde import kde_eval, silverman_h
-from .lscv import lscv_H, lscv_h
+from .lscv import collapse, lscv_H, lscv_h
 from .plugin import plugin_bandwidth
 
 SQRT1_2 = 1.0 / math.sqrt(2.0)
@@ -183,7 +183,9 @@ class KDESynopsis:
     matrix (LSCV_H); exactly one of `h`/`H` is set.  An LSCV_h synopsis also
     keeps what its bandwidth is made of, per axis: the grid minimiser
     `grid_h` of g(h) (eq. 24, for H = h^2 sigma^2) and the sample standard
-    deviation `sigma` (eq. 22), with h = grid_h * sigma.
+    deviation `sigma` (eq. 22), with h = grid_h * sigma; and `grid_rows`,
+    the points each axis's grid sums ran over: the sample's n, or u_p where
+    they ran over the axis's distinct values (`core.lscv.collapse`).
     """
     x: jax.Array                  # retained sample (the synopsis payload)
     h: Optional[jax.Array] = None # scalar or (d,) diagonal bandwidth
@@ -192,15 +194,17 @@ class KDESynopsis:
     selector: str = "plugin"
     grid_h: Optional[jax.Array] = None  # LSCV_h grid minimiser(s)
     sigma: Optional[jax.Array] = None   # LSCV_h sample std deviation(s)
+    grid_rows: Optional[Tuple[int, ...]] = None  # LSCV_h points per axis
 
     @classmethod
     def fit(cls, data: jax.Array, selector: str = "plugin", max_sample: int = 4096,
             seed: int = 0, backend: str = "jnp") -> "KDESynopsis":
+        host = data if isinstance(data, np.ndarray) else None
         data = jnp.asarray(data, jnp.float32)
         n_source = data.shape[0]
         if n_source > max_sample:   # numerosity reduction (paper §2.1)
             idx = jax.random.permutation(jax.random.PRNGKey(seed), n_source)[:max_sample]
-            sample = data[idx]
+            sample, host = data[idx], None
         else:
             sample = data
         if selector == "plugin":
@@ -225,13 +229,24 @@ class KDESynopsis:
             # does) and bandwidth h_j sigma_j
             axes = [sample] if sample.ndim == 1 else \
                 [sample[:, j] for j in range(sample.shape[1])]
-            fits = [lscv_h(a, backend=backend) for a in axes]
+            # a tied axis sums its pairs over its distinct values (the
+            # Pallas kernel's weighted variant), where that is cheaper
+            ties = [None] * len(axes)
+            if backend == "pallas":
+                cols = np.asarray(sample if host is None else host,
+                                  np.float32).reshape(len(sample), -1)
+                ties = [collapse(c) for c in cols.T]
+            fits = [lscv_h(a, backend=backend, ties=t)
+                    for a, t in zip(axes, ties)]
             grid_h = jnp.stack([f.h for f in fits])
             sigma = jnp.sqrt(jnp.stack([f.sigma[0, 0] for f in fits]))
             if sample.ndim == 1:
                 grid_h, sigma = grid_h[0], sigma[0]
+            rows = tuple(len(sample) if t is None else t.values.size
+                         for t in ties)
             return cls(x=sample, h=grid_h * sigma, n_source=n_source,
-                       selector=selector, grid_h=grid_h, sigma=sigma)
+                       selector=selector, grid_h=grid_h, sigma=sigma,
+                       grid_rows=rows)
         if selector == "lscv_H":
             res = lscv_H(sample if sample.ndim == 2 else sample[:, None])
             return cls(x=sample, H=res.H, n_source=n_source, selector=selector)

@@ -9,7 +9,9 @@ LSCV_h  — scalar bandwidth h, any d: brute-force minimisation of g(h) (eq. 24)
               per-h partial sums for the whole grid in one pass.  Same FLOPs as
               (a), O(chunk*n) memory instead of O(n^2)        [store_s=False]
           (c) (b) as one Pallas kernel over the upper-triangle tiles only,
-              each pair visited once, S kept in VMEM [backend="pallas"]
+              each pair visited once, S kept in VMEM [backend="pallas"];
+              on a tied 1-D sample over its distinct values, weighted by
+              their multiplicities, where that takes fewer pairs [ties=]
 LSCV_H  — full SPD bandwidth matrix: Nelder-Mead over a log-Cholesky
           parametrisation of H (guarantees SPD — the paper instead rejects
           non-SPD candidates inside NM; see DESIGN.md §2), objective g(H)
@@ -20,10 +22,11 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import gaussian as G
 from .nelder_mead import minimize as nm_minimize
@@ -126,13 +129,49 @@ def _t_sums_streaming(x: jax.Array, sigma_inv: jax.Array, scales: jax.Array,
     return scan_slabs(consume, jnp.zeros((scales.shape[0],), x.dtype))
 
 
+class Ties(NamedTuple):
+    """A 1-D sample as its u distinct values with their multiplicities m_a,
+    padded to u_p points (a power of two, at least the 128-point least tile
+    of the pair kernels) with multiplicity 0.  `tied` is the number of pairs
+    at distance 0, sum_a C(m_a, 2), as a float32 pair (hi, lo) that sums
+    to it exactly."""
+    values: np.ndarray    # (u_p,) float32
+    weights: np.ndarray   # (u_p,) float32
+    tied: np.ndarray      # (2,) float32
+
+
+def distinct_values(col: np.ndarray) -> Ties:
+    """`col` (n,) as its distinct values, however many there are."""
+    v, m = np.unique(np.asarray(col, np.float32), return_counts=True)
+    u_p = max(128, 1 << (v.size - 1).bit_length())
+    tied = int(np.sum(m * (m - 1) // 2))
+    hi = np.float32(tied)
+    return Ties(np.pad(v, (0, u_p - v.size), mode="edge"),
+                np.pad(m, (0, u_p - v.size)).astype(np.float32),
+                np.asarray([hi, tied - int(hi)], np.float32))
+
+
+def collapse(col: np.ndarray) -> Optional[Ties]:
+    """`col`'s distinct values where summing over them takes at most a
+    quarter of the pairs (u_p <= n / 2), else None."""
+    ties = distinct_values(col)
+    return ties if 2 * ties.values.size <= col.size else None
+
+
 @partial(jax.jit, static_argnames=("n_h", "store_s", "chunk", "backend"))
 def lscv_h(x: jax.Array, n_h: int = N_H_DEFAULT, store_s: bool = False,
-           chunk: int = 128, backend: str = "jnp") -> LSCVhResult:
-    """Full LSCV_h algorithm (paper §6.2 steps 1-7). x: (n, d)."""
+           chunk: int = 128, backend: str = "jnp",
+           ties: Optional[Ties] = None) -> LSCVhResult:
+    """Full LSCV_h algorithm (paper §6.2 steps 1-7). x: (n, d).
+
+    `ties` (1-D x, pallas backend): x's distinct values (`collapse(x)`);
+    the grid sums then run over them with their multiplicities.  Sigma,
+    the grid and n in eq. 43 still come from x."""
     if x.ndim == 1:
         x = x[:, None]
     n, d = x.shape
+    if ties is not None and (backend != "pallas" or d != 1):
+        raise ValueError("ties take a 1-D sample on the pallas backend")
 
     # Steps 1-3: covariance, det, inverse (sequential scalar work in the paper).
     sigma = covariance(x)
@@ -150,7 +189,12 @@ def lscv_h(x: jax.Array, n_h: int = N_H_DEFAULT, store_s: bool = False,
     t_lo = jnp.zeros_like(h_grid)
     if backend == "pallas":
         from repro.kernels import ops as kops
-        t_sums, t_lo = kops.lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk)
+        if ties is None:
+            t_sums, t_lo = kops.lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk)
+        else:
+            t_sums, t_lo = kops.lscv_grid_sums(
+                ties.values, sigma_inv, scales, c_k, c_kk,
+                weights=ties.weights, tied=ties.tied)
     elif store_s:
         s_matrix = pairwise_sv_matrix(x, sigma_inv, chunk)
         rows = jnp.arange(n)

@@ -58,8 +58,12 @@ STATE_FORMAT = 1     # bump on incompatible to_state layout changes
 # Bandwidth fits (`_fit_cached`) count in the process-global registry, as the
 # kernels' counters do, so that a reader with no store handle (a benchmark's
 # per-layer reader) finds them.  Declared here, at zero and unlabelled, so
-# that the counter is there before the process's first fit.
+# that the counter is there before the process's first fit.  An LSCV_h fit
+# also counts its one-axis grid searches, `collapsed=1` where one ran over
+# the axis's distinct values, and the (pair, grid point) terms they summed.
 obs.get_registry().counter("aqp.synopsis.fits")
+obs.get_registry().counter("aqp.synopsis.axis_fits")
+obs.get_registry().counter("aqp.synopsis.pair_terms")
 
 
 class Reservoir:
@@ -1280,18 +1284,26 @@ class TelemetryStore:
             # PLUGIN and LSCV_h run their pair sums on the caller's backend;
             # LSCV_H keeps its jnp Nelder-Mead
             backend = backend if selector in ("plugin", "lscv_h") else "jnp"
-            grid = {}
-            if selector == "lscv_h":        # d one-axis grid searches
-                grid = {"n_h": N_H_DEFAULT,
-                        "pair_terms": d * n * (n - 1) // 2 * N_H_DEFAULT}
+            grid = {"n_h": N_H_DEFAULT} if selector == "lscv_h" else {}
+            reg = obs.get_registry()
             with obs.span("synopsis.fit", n=n, d=d, selector=selector,
                           backend=backend, pallas=int(backend == "pallas"),
-                          **grid):
+                          **grid) as sp:
                 syn = KDESynopsis.fit(data, selector=selector,
                                       max_sample=self.capacity,
                                       backend=backend)
-            obs.get_registry().counter("aqp.synopsis.fits", selector=selector,
-                                       stale=int(stale)).inc()
+                if syn.grid_rows is not None:   # d one-axis grid searches
+                    terms = sum(u * (u - 1) // 2 for u in syn.grid_rows) \
+                        * N_H_DEFAULT
+                    sp.set(pair_terms=terms)
+                    for u in syn.grid_rows:
+                        reg.counter("aqp.synopsis.axis_fits",
+                                    selector=selector,
+                                    collapsed=int(u < n)).inc()
+                    reg.counter("aqp.synopsis.pair_terms",
+                                selector=selector).inc(terms)
+            reg.counter("aqp.synopsis.fits", selector=selector,
+                        stale=int(stale)).inc()
             # scale against the FULL stream: every tier is a uniform sample
             # of it, so tier answers are unbiased for the same relation
             syn.n_source = res.n_seen

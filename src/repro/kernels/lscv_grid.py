@@ -30,6 +30,17 @@ VMEM:
     pairwise with their rounding errors carried, and returned as a float32
     pair (hi, lo): g(h)'s neighbours near its minimum can differ by less
     than one float32 rounding, so the argmin is taken on the pair.
+
+Weighted (`weights` given): the points are a sample's distinct values v_a
+with multiplicities m_a, and the same sum over the sample's pairs is
+
+    sum_{i<j} T~ = sum_a C(m_a, 2) T~(0) + sum_{a<b} m_a m_b T~(v_a - v_b).
+
+Each tile then also forms W = m_a m_b once, in a second VMEM scratch beside
+S, and accumulates sum W e and sum W e^2 (one more multiply per pair);
+padded points have m = 0.  The tied pairs' term, T~(0) = c_kk (1 - r) times
+the integer `tied` = sum_a C(m_a, 2), comes in as an exact float32 pair
+and is added to the sums with its rounding carried.
 """
 from __future__ import annotations
 
@@ -40,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.reductions import pair_sum, two_prod
+from repro.core.reductions import pair_sum, two_prod, two_sum
 
 from .triangle import bx_to_ql, n_tri_tiles
 from .tuning import resolve_tile, square_tile
@@ -66,8 +77,13 @@ def _tile_s(e, f_t, m, d: int):
     return jnp.maximum(qe + qf - 2.0 * cross, 0.0)
 
 
-def _kernel(m_ref, c_ref, r_ref, row_ref, col_ref, out_ref, s_ref, *,
-            n: int, k: int, d: int, n_h: int):
+def _kernel(m_ref, c_ref, r_ref, row_ref, col_ref, *refs,
+            n: int, k: int, d: int, n_h: int, weighted: bool):
+    if weighted:
+        wr_ref, wc_ref, out_ref, s_ref, w_ref = refs
+        w_ref[...] = wr_ref[...] * wc_ref[...]        # m_a m_b, (k, k)
+    else:
+        out_ref, s_ref = refs
     q, l = bx_to_ql(pl.program_id(0))
     ii = q * k + jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
     jj = l * k + jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
@@ -88,9 +104,12 @@ def _kernel(m_ref, c_ref, r_ref, row_ref, col_ref, out_ref, s_ref, *,
             c = c_ref[b * _SUBLANES + t]              # -1 / (4 h^2), SMEM
 
             def row_block(u, acc):
-                sv = s_ref[pl.ds(pl.multiple_of(u * _SUBLANES, _SUBLANES),
-                                 _SUBLANES), :]
-                e = jnp.exp(sv * c)
+                at = pl.ds(pl.multiple_of(u * _SUBLANES, _SUBLANES),
+                           _SUBLANES)
+                e = jnp.exp(s_ref[at, :] * c)
+                if weighted:
+                    we = w_ref[at, :] * e
+                    return acc[0] + we, acc[1] + we * e
                 return acc[0] + e, acc[1] + e * e
 
             def trip(v, acc):
@@ -116,27 +135,35 @@ def _kernel(m_ref, c_ref, r_ref, row_ref, col_ref, out_ref, s_ref, *,
 
 
 def lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, scales: jax.Array,
-                   c_k, c_kk, tile=None, interpret: bool = True):
+                   c_k, c_kk, tile=None, interpret: bool = True,
+                   weights=None, tied=None):
     """For each h on the grid: sum_{i<j} T~(x_i - x_j), as a float32 pair
     (hi, lo) of (n_h,) arrays whose sum carries the digits one float32
     would round away (`core.reductions.pair_sum`).
 
-    x: (n, d) or (n,); sigma_inv: (d, d); scales: (n_h,), -1 / (4 h^2).  `tile` resolves at call time:
+    x: (n, d) or (n,); sigma_inv: (d, d); scales: (n_h,), -1 / (4 h^2).
+    With `weights` (n,), x holds distinct values and `weights` their
+    multiplicities (0 on padding), and `tied` (2,) is sum_a C(m_a, 2) as a
+    float32 pair (hi, lo) summing to it exactly: the sums are then those of
+    the sample the values stand for.  `tile` resolves at call time:
     kwarg > REPRO_LSCV_TILE > module default, rounded as `square_tile`
     rounds it."""
     tile = resolve_tile("REPRO_LSCV_TILE", TILE, tile)
-    return _lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk, tile, interpret)
+    return _lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk, weights, tied,
+                           tile, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, scales: jax.Array,
-                    c_k, c_kk, tile: int, interpret: bool) -> jax.Array:
+                    c_k, c_kk, weights, tied, tile: int,
+                    interpret: bool) -> jax.Array:
     x = x.astype(jnp.float32)
     if x.ndim == 1:
         x = x[:, None]
     n, d = x.shape
     k = square_tile(tile, n)
     xp = jnp.pad(x, ((0, (-n) % k), (0, 0)))
+    weighted = weights is not None
     n_tiles = xp.shape[0] // k
     n_h = scales.shape[0]
     n_hp = -(-n_h // _SUBLANES) * _SUBLANES
@@ -147,25 +174,38 @@ def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, scales: jax.Array,
     c_kk = jnp.asarray(c_kk, jnp.float32)
     r = (2.0 * c_k / c_kk).reshape(1)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    rows = pl.BlockSpec((k, d), lambda bx: (bx_to_ql(bx)[0], 0))     # rows q
+    cols = pl.BlockSpec((d, k), lambda bx: (0, bx_to_ql(bx)[1]))     # cols l
+    operands = [sigma_inv.astype(jnp.float32), c, r, xp, xp.T]
+    in_specs = [pl.BlockSpec((d, d), lambda bx: (0, 0)),         # Sigma^-1
+                smem, smem, rows, cols]                          # scales, r
+    scratch = [pltpu.VMEM((k, k), jnp.float32)]                  # S
+    if weighted:
+        wp = jnp.pad(weights.astype(jnp.float32), (0, xp.shape[0] - n))
+        operands += [wp[:, None], wp[None, :]]
+        in_specs += [pl.BlockSpec((k, 1), lambda bx: (bx_to_ql(bx)[0], 0)),
+                     pl.BlockSpec((1, k), lambda bx: (0, bx_to_ql(bx)[1]))]
+        scratch.append(pltpu.VMEM((k, k), jnp.float32))          # W
 
     partials = pl.pallas_call(
-        functools.partial(_kernel, n=n, k=k, d=d, n_h=n_hp),
+        functools.partial(_kernel, n=n, k=k, d=d, n_h=n_hp,
+                          weighted=weighted),
         grid=(n_tri_tiles(n_tiles),),
-        in_specs=[
-            pl.BlockSpec((d, d), lambda bx: (0, 0)),              # Sigma^-1
-            smem,                                                 # -1/(4h^2)
-            smem,                                                 # r
-            pl.BlockSpec((k, d), lambda bx: (bx_to_ql(bx)[0], 0)),   # rows q
-            pl.BlockSpec((d, k), lambda bx: (0, bx_to_ql(bx)[1])),   # cols l
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n_hp, _LANES),
                                lambda bx: (bx_to_ql(bx)[1], 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles, n_hp, _LANES), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((k, k), jnp.float32)],
+        scratch_shapes=scratch,
         interpret=interpret,
         name="_lscv_grid_sums",
-    )(sigma_inv.astype(jnp.float32), c, r, xp, xp.T)
+    )(*operands)
     hi, lo = pair_sum(
         jnp.transpose(partials, (1, 0, 2)).reshape(n_hp, n_tiles * _LANES))
-    t_hi, t_lo = two_prod(hi[:n_h], c_kk)
-    return t_hi, t_lo + lo[:n_h] * c_kk
+    hi, lo = hi[:n_h], lo[:n_h]
+    if weighted:                    # + (1 - r) sum_a C(m_a, 2), carried
+        one_r = 1.0 - r[0]          # exact: r = 2 c_k / c_kk >= 2
+        p, p_err = two_prod(one_r, tied[0].astype(jnp.float32))
+        hi, err = two_sum(hi, p)
+        lo = lo + err + p_err + one_r * tied[1].astype(jnp.float32)
+    t_hi, t_lo = two_prod(hi, c_kk)
+    return t_hi, t_lo + lo * c_kk

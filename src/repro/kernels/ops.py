@@ -69,14 +69,16 @@ def gh_fused_sum(x, h_inv, c_k, c_kk, tile=None):
         n=x.shape[0], d=d, tile=tile)
 
 
-def lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk, tile=None):
+def lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk, tile=None, weights=None,
+                   tied=None):
     (tile,) = _tune.resolve(
         "lscv_grid_sums", {"n": x.shape[0], "G": scales.shape[0]},
         tile=(tile, "REPRO_LSCV_TILE", _lg.TILE))
     return _dispatch(
         "lscv_grid_sums",
         lambda interp: _lg.lscv_grid_sums(x, sigma_inv, scales, c_k, c_kk,
-                                          tile=tile, interpret=interp),
+                                          tile=tile, interpret=interp,
+                                          weights=weights, tied=tied),
         n=x.shape[0], G=scales.shape[0], tile=tile)
 
 

@@ -674,6 +674,40 @@ def test_fit_counters_count_refits_an_insert_forces(rng):
         assert counts() == (3, 1)
 
 
+def test_lscv_fit_counts_collapsed_axes_and_pair_terms(fit_spans):
+    """An LSCV_h fit counts one `aqp.synopsis.axis_fits` per axis,
+    `collapsed=1` where the axis's ties leave u_p <= n / 2 distinct values
+    (integers 0..99 at 512 rows: 128 points) and `collapsed=0` elsewhere (a
+    continuous column), and the (pair, grid point) terms each summed in
+    `aqp.synopsis.pair_terms` and the fit span's `pair_terms`."""
+    from repro import obs
+    rng = np.random.default_rng(8)
+    store = TelemetryStore(capacity=512, seed=0)
+    store.add_batch({"k": rng.integers(0, 100, 2000).astype(np.float32),
+                     "v": rng.normal(0.0, 1.0, 2000).astype(np.float32)})
+    reg = obs.get_registry()
+
+    def counts():
+        return (reg.sum_counter("aqp.synopsis.axis_fits", selector="lscv_h",
+                                collapsed=1),
+                reg.sum_counter("aqp.synopsis.axis_fits", selector="lscv_h",
+                                collapsed=0),
+                reg.sum_counter("aqp.synopsis.pair_terms", selector="lscv_h"))
+
+    before = counts()
+    eng = store.engine(selector="lscv_h", backend="pallas")
+    eng.execute([AqpQuery("count", (Range("k", 10.0, 40.0),)),
+                 AqpQuery("count", (Range("v", -1.0, 1.0),))])
+    after = counts()
+    terms = {"k": 128 * 127 // 2 * 150, "v": 512 * 511 // 2 * 150}
+    assert [a - b for a, b in zip(after, before)] == \
+        [1, 1, terms["k"] + terms["v"]]
+    spans = [sp for sp in obs.get_tracer().spans() if sp.name == "synopsis.fit"]
+    assert sorted(sp.attrs["pair_terms"] for sp in spans) == sorted(
+        terms.values())
+    assert store.synopsis("k", "lscv_h").grid_rows == (128,)
+
+
 # --- batched QMC fallback ----------------------------------------------------
 
 def test_batched_qmc_matches_per_query_loop(rng):

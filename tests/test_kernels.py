@@ -81,6 +81,61 @@ def test_lscv_grid(rng, n, d, n_h):
     np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-3, atol=1e-3)
 
 
+def _tied_sample(rng, kind: str, n: int) -> np.ndarray:
+    """Integers 16..2047 (seq_len's), four codes (model_id's), or half the
+    rows on ten integers and half continuous."""
+    if kind == "integers":
+        x = rng.integers(16, 2048, n)
+    elif kind == "codes":
+        x = rng.integers(0, 4, n)
+    else:
+        x = np.where(rng.random(n) < 0.5, rng.integers(0, 10, n),
+                     rng.normal(5.0, 3.0, n))
+    return x.astype(np.float32)
+
+
+@pytest.mark.parametrize("kind", ["integers", "codes", "half-tied"])
+def test_lscv_grid_weighted(rng, kind):
+    """Over a sample's distinct values with their multiplicities, the
+    kernel's grid sums are the expanded sample's: the dense oracle's to its
+    float32 rounding (<= 5.3e-6 of the sum here), the unweighted kernel's
+    float32 pairs to theirs (<= 2.2e-7)."""
+    from repro.core.lscv import distinct_values, exp_scales
+    x = _tied_sample(rng, kind, 1024)
+    ties = distinct_values(x)
+    m = jnp.asarray([[1.0 / float(np.var(x, ddof=1))]], jnp.float32)
+    scales = exp_scales(jnp.linspace(0.05, 1.0, 13).astype(jnp.float32))
+    hi, lo = ops.lscv_grid_sums(jnp.asarray(ties.values), m, scales, 0.3, 0.2,
+                                tile=128, weights=jnp.asarray(ties.weights),
+                                tied=jnp.asarray(ties.tied))
+    got = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+    full_hi, full_lo = ops.lscv_grid_sums(jnp.asarray(x), m, scales, 0.3, 0.2,
+                                          tile=128)
+    full = np.asarray(full_hi, np.float64) + np.asarray(full_lo, np.float64)
+    dense = ref.lscv_grid_sums(jnp.asarray(x)[:, None], m, scales, 0.3, 0.2)
+    np.testing.assert_allclose(got, np.asarray(dense, np.float64), rtol=2e-5)
+    np.testing.assert_allclose(got, full, rtol=1e-6)
+
+
+def test_lscv_grid_counts_tied_pairs_exactly():
+    """72,001,140 pairs at distance 0 (over 2^24, so one float32 would
+    round them by 4) and the rest far apart: the sums are c_kk (1 - r)
+    times that count, with r = 2 c_k / c_kk = 3, to 1e-12."""
+    from repro.core.lscv import distinct_values, exp_scales, h_grid_for
+    x = np.r_[np.zeros(12000), np.full(120, 1000.0)].astype(np.float32)
+    ties = distinct_values(x)
+    tied = 12000 * 11999 // 2 + 120 * 119 // 2
+    assert tied > 2 ** 24 and int(np.float32(tied)) != tied
+    assert float(ties.tied[0]) + float(ties.tied[1]) == tied
+    m = jnp.asarray([[1.0 / float(np.var(x, ddof=1))]], jnp.float32)
+    scales = exp_scales(h_grid_for(x.size, 1))
+    hi, lo = ops.lscv_grid_sums(jnp.asarray(ties.values), m, scales, 0.375,
+                                0.25, weights=jnp.asarray(ties.weights),
+                                tied=jnp.asarray(ties.tied))
+    got = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
+    np.testing.assert_allclose(got, -0.5 * tied, rtol=1e-12)
+
+
 @pytest.mark.parametrize("m,n,d", [(3, 17, 1), (65, 64, 2), (128, 500, 8)])
 def test_kde_eval(rng, m, n, d):
     pts = jnp.asarray(rng.normal(0, 1, (m, d)).astype(np.float32))

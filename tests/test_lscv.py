@@ -61,6 +61,58 @@ def test_inv_h_is_the_exponent_scales_bandwidth():
     assert np.max(np.abs(np.asarray(hi, np.float64) / want - 1.0)) > 1e-9
 
 
+def _tied(kind: str, n: int) -> np.ndarray:
+    rng = np.random.default_rng(17)
+    if kind == "integers":                  # seq_len's 16..2047
+        x = rng.integers(16, 2048, n)
+    elif kind == "codes":                   # model_id's four codes
+        x = rng.integers(0, 4, n)
+    else:                                   # half on ten integers
+        x = np.where(rng.random(n) < 0.5, rng.integers(0, 10, n),
+                     rng.normal(5.0, 3.0, n))
+    return x.astype(np.float32)
+
+
+@pytest.mark.parametrize("kind", ["integers", "codes", "half-tied"])
+def test_lscv_h_over_distinct_values_picks_the_same_h(kind):
+    """The grid sums over a tied sample's distinct values with their
+    multiplicities pick the grid point the sums over every pair pick, with
+    sigma from the whole sample: the same h_j sigma_j, and g within float32
+    rounding of its largest value."""
+    from repro.core.lscv import distinct_values
+    x = jnp.asarray(_tied(kind, 2048))
+    full = lscv_h(x, n_h=40, backend="pallas")
+    ties = distinct_values(np.asarray(x))
+    got = lscv_h(x, n_h=40, backend="pallas", ties=ties)
+    assert float(got.h) == float(full.h)
+    assert float(got.sigma[0, 0]) == float(full.sigma[0, 0])
+    g = np.asarray(full.g_values)
+    np.testing.assert_allclose(got.g_values, g, rtol=0,
+                               atol=1e-6 * np.max(np.abs(g)))
+
+
+def test_fit_collapses_only_where_ties_pay():
+    """A served LSCV_h fit sums over distinct values where u_p <= n / 2 and
+    keeps the call over every pair elsewhere: a continuous axis's bandwidth
+    is bit-identical to `lscv_h` on the column, a 4-code axis's is the
+    same grid point from 128 points."""
+    from repro.core.aqp import KDESynopsis
+    rng = np.random.default_rng(5)
+    x = np.stack([rng.normal(0.0, 2.0, 512),
+                  rng.integers(0, 4, 512)], axis=1).astype(np.float32)
+    syn = KDESynopsis.fit(x, selector="lscv_h", max_sample=512,
+                          backend="pallas")
+    assert syn.grid_rows == (512, 128)
+    cont = lscv_h(jnp.asarray(x[:, 0]), backend="pallas")
+    assert np.asarray(syn.grid_h)[0] == np.asarray(cont.h)
+    assert np.asarray(syn.h)[0] == np.asarray(
+        cont.h * jnp.sqrt(cont.sigma[0, 0]))
+    codes = lscv_h(jnp.asarray(x[:, 1]), backend="pallas")
+    assert np.asarray(syn.grid_h)[1] == np.asarray(codes.h)
+    assert KDESynopsis.fit(x, selector="lscv_h", max_sample=512,
+                           backend="jnp").grid_rows == (512, 512)
+
+
 def test_h0_1d_is_silverman():
     # eq. (28) for d=1 must reduce to (4/3)^(1/5) n^(-1/5)
     n = 1000

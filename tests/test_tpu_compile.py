@@ -4,7 +4,8 @@ Each kernel is lowered with `interpret=False` for one chip of a described
 `v5e:2x2` topology at the shapes the serving path produces (32,768-row
 reservoirs, d=2, batches padded pow2-then-x64 below and above one query
 tile, 4,096 QMC nodes, 2,048 RFF features, the 150-point LSCV_h grid, the
-served 1-D LSCV_h fit at 32,768 rows) and compiled by the installed TPU
+served 1-D LSCV_h fit at 32,768 rows, and its weighted variant over a tied
+axis's 2,048 or 128 distinct values) and compiled by the installed TPU
 compiler; the compiled program must contain
 the Mosaic kernel (`tpu_custom_call`) under the kernel's stable name (the
 `name=` of its `pallas_call`, which a profiler trace shows as the
@@ -110,6 +111,15 @@ def _cases():
                  x, si, sc, ck, ckk, interpret=False),
              [((rows, d), F32), ((d, d), F32), ((N_H,), F32), ((), F32),
               ((), F32)]))
+    # the weighted variant over a tied axis's distinct values: seq_len's
+    # 2,048 and model_id's 128 (the least tile)
+    for u in (SUBSAMPLE, 128):
+        cases.append(
+            (f"lscv_grid-weighted{u}",
+             lambda v, si, sc, ck, ckk, w, t: lscv_grid.lscv_grid_sums(
+                 v, si, sc, ck, ckk, interpret=False, weights=w, tied=t),
+             [((u,), F32), ((1, 1), F32), ((N_H,), F32), ((), F32),
+              ((), F32), ((u,), F32), ((2,), F32)]))
     return cases
 
 

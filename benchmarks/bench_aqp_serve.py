@@ -7,7 +7,7 @@ clients (one outstanding query each):
   per-call   — every client answers each query with its own
                `engine.execute([q])` call: per-query planning + dispatch,
                nothing shared across callers (the pre-admission pattern in
-               `serve --mode aqp`)
+               `repro.launch.serve`)
   admission  — every client submits to one shared `AqpSession`
                (watermark = client count): pending specs coalesce across
                clients into micro-batches keyed by (column tuple, selector,
@@ -60,7 +60,7 @@ def _client_specs(n_clients: int, per_client: int, ranges):
     """Mixed COUNT/SUM/AVG ranges over ONE column: a single (column,
     selector) bucket, so the micro-batch depth equals the number of
     concurrent clients (the acceptance bar is pinned at depth >= 16).
-    Heterogeneous multi-bucket traffic is covered by `serve --mode aqp`
+    Heterogeneous multi-bucket traffic is covered by `repro.launch.serve`
     and the admission tests."""
     from repro.core import AqpQuery, Range
 

@@ -25,8 +25,7 @@ import time
 SUITES = ("paper_validation", "plugin", "lscv_h", "lscv_H", "table3",
           "kernels", "aqp_batch", "aqp_boxes", "aqp_grouped", "aqp_engine",
           "aqp_rff",
-          "aqp_serve", "aqp_restore", "aqp_progressive", "roofline",
-          "serving")
+          "aqp_serve", "aqp_restore", "aqp_progressive")
 
 # the always-on report lands at the repo root regardless of the cwd the
 # harness was launched from, so CI archiving finds one canonical path

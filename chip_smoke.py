@@ -5,7 +5,7 @@
     python3 chip_smoke.py --chips 4   # the sharded bandwidth selectors only
 
 One chip: 4,194,304 synthetic telemetry rows go into a
-`TelemetryStore(capacity=32768)` exactly as `serve --mode aqp` seeds it
+`TelemetryStore(capacity=32768)` exactly as `repro.launch.serve` seeds it
 (`repro.launch.serve.seed_aqp_store`).  `serve.run_aqp` then fits the
 synopses on the chip (PLUGIN per column and per joint axis, LSCV_H on the
 (loss, latency_ms) joint, the RFF density synopsis above the crossover) and
@@ -288,7 +288,7 @@ def serve_args(backend: str):
     from repro.launch import serve
 
     return serve.parse_args([
-        "--mode", "aqp", "--rows", str(ROWS), "--capacity", str(CAPACITY),
+        "--rows", str(ROWS), "--capacity", str(CAPACITY),
         "--clients", str(CLIENTS), "--per-client", str(PER_CLIENT),
         "--stream-every-ms", "1e9",           # producer quiescent
         "--fullh-frac", "0.25", "--backend", backend])

@@ -5,7 +5,7 @@
     python scripts/validate_metrics.py --bench BENCH_aqp.json
     python scripts/validate_metrics.py --tuning /tmp/tiles.json
 
-Default mode validates a `serve --mode aqp --metrics-out` snapshot
+Default mode validates a `repro.launch.serve --metrics-out` snapshot
 (`obs.export_json` format): the required instruments must be present with
 sane values — queue depth gauge, per-path latency histograms, synopsis
 cache hit/miss counters, and flush-reason counters.  `--bench` validates a

@@ -1,2 +1,1 @@
 from .checkpoint import CheckpointManager
-from .elastic import ElasticController, StragglerMonitor, plan_mesh

@@ -1,4 +1,3 @@
 from .aqp_store import (CategoricalSketch, CountMinSketch, MultiReservoir,
                         Reservoir, SynopsisCache, TelemetryStore,
                         TieredReservoir)
-from .pipeline import TokenPipeline

@@ -27,7 +27,7 @@ reservoir (buffer, stream counters, version, RNG bit-generator state — so
 post-restore sampling is deterministic), every categorical sketch, the joint
 registrations with their backfill flags, and the fitted synopses in the
 cache.  `save(path)`/`load(path)` put that state behind the atomic keep-k
-`CheckpointManager` (repro.checkpoint), so a `serve --mode aqp` restart
+`CheckpointManager` (repro.checkpoint), so a `repro.launch.serve` restart
 warm-starts instead of refitting — and exact-Eq coverage, which requires a
 sketch to have seen the *whole* stream, survives the restart.  Snapshots are
 taken under the store's write lock, so a snapshot racing `add_batch` can

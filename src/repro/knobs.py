@@ -22,9 +22,8 @@ uncached on purpose — knobs resolve at *call* time so a late env change or
 an in-process sweep can move them without a restart (PR 9's import-freeze
 fix depends on this).
 
-This module imports nothing outside the standard library, so the earliest
-riser (``launch/dryrun.py`` sets XLA_FLAGS before jax initialises) can use
-it safely.
+This module imports nothing outside the standard library, so code that
+runs before jax initialises can use it safely.
 """
 from __future__ import annotations
 
@@ -140,9 +139,6 @@ register("REPRO_BENCH_QUICK", "bool", False,
 register("REPRO_TUNING_CACHE", "path", "",
          "Path of the persisted measured-tile cache (kernels/autotune.py); "
          "sweeps write it, fresh processes lazy-load it with zero re-sweeps.")
-register("REPRO_DRYRUN_DEVICES", "int", 512,
-         "Placeholder host-device count for launch/dryrun.py meshes (must "
-         "be set before jax initialises).")
 
 register("REPRO_KDE_CHUNK", "int", 256,
          "Evaluation-point chunk size for the exact kde_eval_H pass "

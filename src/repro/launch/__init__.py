@@ -1,2 +1,1 @@
-from .mesh import (dp_axes, make_host_mesh, make_mesh_from_spec,
-                   make_production_mesh, tp_size)
+"""Entry points: `serve` runs the AQP admission loop over a TelemetryStore."""

@@ -1,21 +1,15 @@
-"""Serving drivers.
+"""The AQP serving driver: a long-lived admission loop over a TelemetryStore.
 
-LM mode (default): prefill + greedy decode on a smoke config.
+Concurrent query clients submit heterogeneous AqpQuery specs — 1-D ranges,
+multi-column box predicates (eq. 11), categorical equality on a dictionary
+column — into one `AqpSession` while a producer keeps streaming telemetry
+batches into the store (bumping synopsis versions mid-flight).  The session
+coalesces specs across clients into micro-batches keyed by (column tuple,
+selector, synopsis version) and flushes them on a batch-size watermark or
+max-delay deadline; the summary reports queue depth, flush reasons,
+per-flush batch sizes, and version invalidations.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --smoke \
-        --batch 4 --prompt-len 16 --max-new 16
-
-AQP mode: a long-lived admission loop over a TelemetryStore.  Concurrent
-query clients submit heterogeneous AqpQuery specs — 1-D ranges, multi-column
-box predicates (eq. 11), categorical equality on a dictionary column — into
-one `AqpSession` while a producer keeps streaming telemetry batches into the
-store (bumping synopsis versions mid-flight).  The session coalesces specs
-across clients into micro-batches keyed by (column tuple, selector, synopsis
-version) and flushes them on a batch-size watermark or max-delay deadline;
-the summary reports queue depth, flush reasons, per-flush batch sizes, and
-version invalidations.
-
-    PYTHONPATH=src python -m repro.launch.serve --mode aqp \
+    PYTHONPATH=src python -m repro.launch.serve \
         --rows 200000 --clients 8 --per-client 150 --max-delay-ms 5 \
         --selector plugin
 
@@ -26,7 +20,7 @@ the latest snapshot instead of re-seeding — the exact categorical path stays
 active (sketch coverage survives) and no synopsis is refitted.  `--max-pending`
 bounds the admission queue (block or shed, `--overflow`).
 
-    PYTHONPATH=src python -m repro.launch.serve --mode aqp \
+    PYTHONPATH=src python -m repro.launch.serve \
         --snapshot-dir /tmp/aqp-snap --snapshot-every 5 --restore
 
 Observability: `--metrics-out FILE` enables `repro.obs` (span tracing,
@@ -36,35 +30,13 @@ seconds (atomic replace — a scraper never reads a torn file), and prints an
 end-of-run summary table; `--trace-out FILE` appends the span ring as JSON
 lines on exit.  See docs/observability.md for the metric catalogue.
 
-    PYTHONPATH=src python -m repro.launch.serve --mode aqp \
+    PYTHONPATH=src python -m repro.launch.serve \
         --metrics-out /tmp/aqp-metrics.json --metrics-every 0.5
 """
 from __future__ import annotations
 
 import argparse
 import time
-
-
-def run_lm(args) -> None:
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs.base import get_config
-    from repro.models import build_model
-    from repro.train import greedy_generate
-
-    cfg = get_config(args.arch, smoke=args.smoke)
-    model = build_model(cfg)
-    params = model.init(jax.random.key(0))
-    prompt = jax.random.randint(jax.random.key(1), (args.batch, args.prompt_len),
-                                0, cfg.vocab_size, jnp.int32)
-    t0 = time.time()
-    out = greedy_generate(model, params, prompt, args.max_new)
-    dt = time.time() - t0
-    toks = args.batch * args.max_new
-    print(f"[serve] generated {out.shape} in {dt:.2f}s "
-          f"({toks / dt:.1f} tok/s incl. compile)")
-    print(out[0].tolist())
 
 
 def make_query_mix(n_queries: int, ranges, seed: int = 0):
@@ -212,7 +184,7 @@ def _print_metrics_summary(store) -> None:
 
 
 def seed_aqp_store(args):
-    """The store `--mode aqp` serves: warm-started from the latest snapshot
+    """The store `serve` serves: warm-started from the latest snapshot
     under --snapshot-dir (--restore), or seeded with --rows synthetic
     telemetry rows.  Returns (store, rows_seen, restored_step, telemetry);
     telemetry is the seeded column dict, None after a restore."""
@@ -252,7 +224,7 @@ def seed_aqp_store(args):
 
 
 def run_aqp(args, seeded=None) -> dict:
-    """Serve one `--mode aqp` run and print its summary.  `seeded` is a
+    """Serve one run and print its summary.  `seeded` is a
     `seed_aqp_store(args)` result to serve from (default: seed a new one).
     Returns the answers: {"results": the clients' AqpResults in client
     order, "group_by": the GROUP BY results}."""
@@ -472,15 +444,7 @@ def run_aqp(args, seeded=None) -> dict:
 
 def parse_args(argv=None) -> argparse.Namespace:
     """The serve CLI's arguments (`argv=None` reads sys.argv), validated."""
-    from repro.configs.base import ARCH_IDS
-
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", default="lm", choices=["lm", "aqp"])
-    ap.add_argument("--arch", default="llama3.2-1b", choices=list(ARCH_IDS))
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--rows", type=int, default=200_000)
     ap.add_argument("--clients", type=int, default=8,
                     help="concurrent query clients feeding the AqpSession")
@@ -556,10 +520,7 @@ def main() -> None:
     args = parse_args()
     from repro.compile_cache import use_compile_cache
     use_compile_cache()
-    if args.mode == "aqp":
-        run_aqp(args)
-    else:
-        run_lm(args)
+    run_aqp(args)
 
 
 if __name__ == "__main__":

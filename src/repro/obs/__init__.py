@@ -12,7 +12,7 @@ Two kinds of instrumentation with different cost profiles:
     histograms with `block_until_ready` fencing, and kernel profiling.
     Fencing changes dispatch behaviour (it synchronises the device), so
     these are opt-in: set ``REPRO_OBS=1`` in the environment or call
-    :func:`enable` (e.g. ``serve --mode aqp --metrics-out ...`` does).
+    :func:`enable` (e.g. ``repro.launch.serve --metrics-out ...`` does).
     When disabled, `span()` returns a shared no-op object and the kernel
     wrappers take the un-instrumented branch — zero extra jit traces and
     bit-identical numerics, both test-enforced.

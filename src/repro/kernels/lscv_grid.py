@@ -20,16 +20,29 @@ VMEM:
     qe + qf - 2 e M f^T (M symmetric; the cross term on the MXU).  Pairs with i >= j or j >= n get
     S = +inf, so they add exp(-inf) = 0: one mask per tile, shared by the
     whole grid;
-  * the same visit folds the tile into the partials of every h: per h two
-    (8, k) float32 register accumulators over the tile's rows, of e and of
-    e^2 (one exp and four vector operations per pair), combined as
-    sum e - r sum e^2, summed to one 128-lane row and added into its column
-    chunk's (n_h, 128) output block, which stays resident while the
-    column's tiles stream through;
-  * the (column chunk, lane) partials of each h are added outside the kernel
-    pairwise with their rounding errors carried, and returned as a float32
-    pair (hi, lo): g(h)'s neighbours near its minimum can differ by less
-    than one float32 rounding, so the argmin is taken on the pair.
+  * the tile's S values are kept as k / 128 lane groups of (k, 128)
+    (`s_ref[g]`), so a loop index picks a lane group by its leading index,
+    and as S log2(e): exp(S c) = 2^(S log2(e) c), the factor taken once per
+    pair rather than once per (pair, h) (on the TPU `exp` is a multiply by
+    log2(e) and a power of two, and the vector slots set the pace);
+  * the same visit folds the tile into the partials of every h.  One loop
+    trip takes one lane group and a block of `_H_BLOCK` grid points over
+    `_TRIP_ROWS` row blocks of 8 (all k / 8 when fewer): each (8, 128) S
+    vreg is loaded once and evaluated at every point of the block, so a
+    trip is one straight-line run of `_H_BLOCK` x `_TRIP_ROWS` independent
+    exp chains (1,024 at k >= 1,024), one `exp2` and four vector operations
+    per pair.  The block's accumulators are two (8, 128) float32 registers
+    per grid point, of e and of e^2; each of their elements sums the k / 8
+    rows of one lane of the tile, no more;
+  * after a lane group's last trip each point's two accumulators are
+    combined as sum e - r sum e^2 and added, a plain vector add, into its
+    (8, 128) row of the column chunk's (n_h, 8, 128) output block, which
+    stays resident while the column's tiles stream through.  No lane or
+    sublane sum runs in the kernel;
+  * the (column chunk, sublane, lane) partials of each h are added outside
+    the kernel pairwise with their rounding errors carried, and returned as
+    a float32 pair (hi, lo): g(h)'s neighbours near its minimum can differ
+    by less than one float32 rounding, so the argmin is taken on the pair.
 
 Weighted (`weights` given): the points are a sample's distinct values v_a
 with multiplicities m_a, and the same sum over the sample's pairs is
@@ -45,6 +58,7 @@ and is added to the sums with its rounding carried.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +73,9 @@ from .tuning import resolve_tile, square_tile
 TILE = 1024
 _LANES = 128
 _SUBLANES = 8
-_UNROLL = 8                     # row blocks of 8 per loop trip
+_H_BLOCK = 8                    # grid points per loaded S vreg
+_TRIP_ROWS = 128                # row blocks of 8 per loop trip (at most k / 8)
+_LOG2E = math.log2(math.e)      # S is kept as S log2(e)
 
 
 def _tile_s(e, f_t, m, d: int):
@@ -81,57 +97,59 @@ def _kernel(m_ref, c_ref, r_ref, row_ref, col_ref, *refs,
             n: int, k: int, d: int, n_h: int, weighted: bool):
     if weighted:
         wr_ref, wc_ref, out_ref, s_ref, w_ref = refs
-        w_ref[...] = wr_ref[...] * wc_ref[...]        # m_a m_b, (k, k)
     else:
         out_ref, s_ref = refs
     q, l = bx_to_ql(pl.program_id(0))
     ii = q * k + jax.lax.broadcasted_iota(jnp.int32, (k, k), 0)
     jj = l * k + jax.lax.broadcasted_iota(jnp.int32, (k, k), 1)
     s = _tile_s(row_ref[...], col_ref[...], m_ref[...], d)
-    s_ref[...] = jnp.where((ii < jj) & (jj < n), s, jnp.inf)
+    s = jnp.where((ii < jj) & (jj < n), s * _LOG2E, jnp.inf)
+    w = wr_ref[...] * wc_ref[...] if weighted else None    # m_a m_b, (k, k)
+    groups = k // _LANES
+    for g in range(groups):           # lane group g of the tile at s_ref[g]
+        lanes = slice(g * _LANES, (g + 1) * _LANES)
+        s_ref[g] = s[:, lanes]
+        if weighted:
+            w_ref[g] = w[:, lanes]
     r = r_ref[0]                                      # 2 c_k / c_kk, SMEM
 
     @pl.when(q == 0)                  # the column's first tile: fresh block
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    rows = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, _LANES), 0)
+    trip_rows = min(_TRIP_ROWS, k // _SUBLANES)
 
-    def h_block(b, carry):
-        # eight h per trip: one aligned (8, 128) row block of the output
-        blk = jnp.zeros((_SUBLANES, _LANES), jnp.float32)
-        for t in range(_SUBLANES):
-            c = c_ref[b * _SUBLANES + t]              # -1 / (4 h^2), SMEM
+    def block(j, carry):
+        # _H_BLOCK grid points against one lane group, all k rows of it
+        b, g = j // groups, j % groups
+        cs = [c_ref[b * _H_BLOCK + t] for t in range(_H_BLOCK)]   # SMEM
 
-            def row_block(u, acc):
-                at = pl.ds(pl.multiple_of(u * _SUBLANES, _SUBLANES),
-                           _SUBLANES)
-                e = jnp.exp(s_ref[at, :] * c)
-                if weighted:
-                    we = w_ref[at, :] * e
-                    return acc[0] + we, acc[1] + we * e
-                return acc[0] + e, acc[1] + e * e
+        def row_block(u, acc):
+            # one S vreg loaded, evaluated at every grid point of the block
+            at = pl.ds(pl.multiple_of(u * _SUBLANES, _SUBLANES), _SUBLANES)
+            sv = s_ref[g, at, :]
+            wv = w_ref[g, at, :] if weighted else None
+            out = []
+            for t, c in enumerate(cs):
+                e = jnp.exp2(sv * c)                  # exp(S c)
+                we = wv * e if weighted else e
+                out += [acc[2 * t] + we, acc[2 * t + 1] + we * e]
+            return tuple(out)
 
-            def trip(v, acc):
-                # _UNROLL row blocks, unrolled as it lowers (traced once)
-                return jax.lax.fori_loop(
-                    0, _UNROLL, lambda w, a: row_block(v * _UNROLL + w, a),
-                    acc, unroll=True)
+        def trip(v, acc):
+            # trip_rows row blocks, unrolled as it lowers (traced once)
+            return jax.lax.fori_loop(
+                0, trip_rows, lambda i, a: row_block(v * trip_rows + i, a),
+                acc, unroll=True)
 
-            zero = jnp.zeros((_SUBLANES, k), jnp.float32)
-            sum_e, sum_e2 = jax.lax.fori_loop(
-                0, k // (_SUBLANES * _UNROLL), trip, (zero, zero))
-            acc = sum_e - r * sum_e2
-            lanes = acc[:, :_LANES]
-            for g in range(1, k // _LANES):
-                lanes = lanes + acc[:, g * _LANES:(g + 1) * _LANES]
-            part = jnp.sum(lanes, axis=0, keepdims=True)   # (1, 128)
-            blk = jnp.where(rows == t, part, blk)
-        at = pl.multiple_of(b * _SUBLANES, _SUBLANES)
-        out_ref[0, pl.ds(at, _SUBLANES), :] += blk
+        zero = jnp.zeros((_SUBLANES, _LANES), jnp.float32)
+        acc = jax.lax.fori_loop(0, k // (_SUBLANES * trip_rows), trip,
+                                (zero,) * (2 * _H_BLOCK))
+        for t in range(_H_BLOCK):
+            out_ref[0, b * _H_BLOCK + t] += acc[2 * t] - r * acc[2 * t + 1]
         return carry
 
-    jax.lax.fori_loop(0, n_h // _SUBLANES, h_block, 0)
+    jax.lax.fori_loop(0, n_h // _H_BLOCK * groups, block, 0)
 
 
 def lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, scales: jax.Array,
@@ -166,7 +184,7 @@ def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, scales: jax.Array,
     weighted = weights is not None
     n_tiles = xp.shape[0] // k
     n_h = scales.shape[0]
-    n_hp = -(-n_h // _SUBLANES) * _SUBLANES
+    n_hp = -(-n_h // _H_BLOCK) * _H_BLOCK
     # padded grid points get a finite negative scale (their sums are dropped)
     c = jnp.pad(scales.astype(jnp.float32), (0, n_hp - n_h),
                 constant_values=-1.0)
@@ -179,28 +197,30 @@ def _lscv_grid_sums(x: jax.Array, sigma_inv: jax.Array, scales: jax.Array,
     operands = [sigma_inv.astype(jnp.float32), c, r, xp, xp.T]
     in_specs = [pl.BlockSpec((d, d), lambda bx: (0, 0)),         # Sigma^-1
                 smem, smem, rows, cols]                          # scales, r
-    scratch = [pltpu.VMEM((k, k), jnp.float32)]                  # S
+    groups = k // _LANES
+    scratch = [pltpu.VMEM((groups, k, _LANES), jnp.float32)]     # S
     if weighted:
         wp = jnp.pad(weights.astype(jnp.float32), (0, xp.shape[0] - n))
         operands += [wp[:, None], wp[None, :]]
         in_specs += [pl.BlockSpec((k, 1), lambda bx: (bx_to_ql(bx)[0], 0)),
                      pl.BlockSpec((1, k), lambda bx: (0, bx_to_ql(bx)[1]))]
-        scratch.append(pltpu.VMEM((k, k), jnp.float32))          # W
+        scratch.append(pltpu.VMEM((groups, k, _LANES), jnp.float32))  # W
 
     partials = pl.pallas_call(
         functools.partial(_kernel, n=n, k=k, d=d, n_h=n_hp,
                           weighted=weighted),
         grid=(n_tri_tiles(n_tiles),),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_hp, _LANES),
-                               lambda bx: (bx_to_ql(bx)[1], 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, n_hp, _LANES), jnp.float32),
+        out_specs=pl.BlockSpec((1, n_hp, _SUBLANES, _LANES),
+                               lambda bx: (bx_to_ql(bx)[1], 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, n_hp, _SUBLANES, _LANES),
+                                       jnp.float32),
         scratch_shapes=scratch,
         interpret=interpret,
         name="_lscv_grid_sums",
     )(*operands)
-    hi, lo = pair_sum(
-        jnp.transpose(partials, (1, 0, 2)).reshape(n_hp, n_tiles * _LANES))
+    hi, lo = pair_sum(jnp.transpose(partials, (1, 0, 2, 3)).reshape(
+        n_hp, n_tiles * _SUBLANES * _LANES))
     hi, lo = hi[:n_h], lo[:n_h]
     if weighted:                    # + (1 - r) sum_a C(m_a, 2), carried
         one_r = 1.0 - r[0]          # exact: r = 2 c_k / c_kk >= 2

@@ -60,20 +60,25 @@ def test_gh_fused(rng, n, d):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("n,d,n_h", [
-    (100, 2, 5), (257, 3, 13),
-    (100, 1, 5), (100, 3, 5), (256, 1, 13), (256, 2, 13), (256, 3, 13),
-    (300, 1, 13), (300, 2, 13)])
-def test_lscv_grid(rng, n, d, n_h):
-    """The streaming triangular kernel (128-point tiles: n = 100, 257 and
-    300 ragged, 256 two whole tiles) against the dense oracle and the jnp
-    streaming grid."""
+@pytest.mark.parametrize("n,d,n_h,tile", [
+    pytest.param(*c, 128, id="-".join(map(str, c))) for c in (
+        (100, 2, 5), (257, 3, 13),
+        (100, 1, 5), (100, 3, 5), (256, 1, 13), (256, 2, 13), (256, 3, 13),
+        (300, 1, 13), (300, 2, 13))] + [
+    pytest.param(*c, 256, id="-".join(map(str, c)) + "-tile256") for c in (
+        (300, 1, 5), (300, 1, 150), (600, 1, 13), (600, 2, 150))])
+def test_lscv_grid(rng, n, d, n_h, tile):
+    """The streaming triangular kernel against the dense oracle and the jnp
+    streaming grid: 128-point tiles (n = 100, 257 and 300 ragged, 256 two
+    whole tiles), and 256-point tiles of two lane groups (n = 300 ragged in
+    the second, 600 over three tiles), on grids padded to whole blocks of
+    eight points (5 to 8, 13 to 16, 150 to 152)."""
     from repro.core.lscv import _t_sums_streaming, exp_scales
     x = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
     m0 = rng.normal(0, 1, (d, d)).astype(np.float32)
     m = jnp.asarray(0.1 * (m0 @ m0.T) + np.eye(d, dtype=np.float32))
     scales = exp_scales(jnp.linspace(0.3, 2.0, n_h).astype(jnp.float32))
-    hi, lo = ops.lscv_grid_sums(x, m, scales, 0.3, 0.2, tile=128)
+    hi, lo = ops.lscv_grid_sums(x, m, scales, 0.3, 0.2, tile=tile)
     a = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
     b = ref.lscv_grid_sums(x, m, scales, 0.3, 0.2)
     c = _t_sums_streaming(x, m, scales, 0.3, 0.2, chunk=64)
@@ -94,23 +99,28 @@ def _tied_sample(rng, kind: str, n: int) -> np.ndarray:
     return x.astype(np.float32)
 
 
-@pytest.mark.parametrize("kind", ["integers", "codes", "half-tied"])
-def test_lscv_grid_weighted(rng, kind):
+@pytest.mark.parametrize("kind,n,n_h,tile", [
+    pytest.param(k, 1024, 13, 128, id=k)
+    for k in ("integers", "codes", "half-tied")] + [
+    pytest.param("integers", 600, 150, 256, id="integers-600-150-tile256"),
+    pytest.param("half-tied", 1024, 5, 256, id="half-tied-1024-5-tile256")])
+def test_lscv_grid_weighted(rng, kind, n, n_h, tile):
     """Over a sample's distinct values with their multiplicities, the
     kernel's grid sums are the expanded sample's: the dense oracle's to its
     float32 rounding (<= 5.3e-6 of the sum here), the unweighted kernel's
-    float32 pairs to theirs (<= 2.2e-7)."""
+    float32 pairs to theirs (<= 2.2e-7).  At 256-point tiles each tile of
+    distinct values spans two lane groups."""
     from repro.core.lscv import distinct_values, exp_scales
-    x = _tied_sample(rng, kind, 1024)
+    x = _tied_sample(rng, kind, n)
     ties = distinct_values(x)
     m = jnp.asarray([[1.0 / float(np.var(x, ddof=1))]], jnp.float32)
-    scales = exp_scales(jnp.linspace(0.05, 1.0, 13).astype(jnp.float32))
+    scales = exp_scales(jnp.linspace(0.05, 1.0, n_h).astype(jnp.float32))
     hi, lo = ops.lscv_grid_sums(jnp.asarray(ties.values), m, scales, 0.3, 0.2,
-                                tile=128, weights=jnp.asarray(ties.weights),
+                                tile=tile, weights=jnp.asarray(ties.weights),
                                 tied=jnp.asarray(ties.tied))
     got = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)
     full_hi, full_lo = ops.lscv_grid_sums(jnp.asarray(x), m, scales, 0.3, 0.2,
-                                          tile=128)
+                                          tile=tile)
     full = np.asarray(full_hi, np.float64) + np.asarray(full_lo, np.float64)
     dense = ref.lscv_grid_sums(jnp.asarray(x)[:, None], m, scales, 0.3, 0.2)
     np.testing.assert_allclose(got, np.asarray(dense, np.float64), rtol=2e-5)

@@ -49,6 +49,24 @@ def test_pallas_argmin_is_float64s_on_a_near_tie():
     assert float(res.h) == float(res.h_grid[np.argmin(g)])
 
 
+def test_pallas_and_jnp_pick_one_grid_point_on_a_bimodal_sample():
+    """`latency_ms`-shaped data (80% N(40, 8), 20% N(160, 30)), 1,024 rows,
+    on the served 150-point grid: the Pallas kernel's pair sums and the jnp
+    streaming sums give g to float32 rounding and pick the same grid
+    index."""
+    rng = np.random.default_rng(1900)
+    n = 1024
+    x = np.where(rng.random(n) < 0.8, rng.normal(40.0, 8.0, n),
+                 rng.normal(160.0, 30.0, n)).astype(np.float32)
+    got = lscv_h(jnp.asarray(x), backend="pallas")
+    want = lscv_h(jnp.asarray(x))
+    assert got.h_grid.shape == (150,)
+    np.testing.assert_allclose(got.g_values, want.g_values, rtol=3e-4)
+    grid = np.asarray(want.h_grid)
+    assert (np.flatnonzero(grid == float(got.h)).tolist()
+            == np.flatnonzero(grid == float(want.h)).tolist())
+
+
 def test_inv_h_is_the_exponent_scales_bandwidth():
     """g's 1 / h is 2 sqrt(-scale) of the float32 scale the sums used, to
     far below a float32 rounding: the pair off float64 by < 1e-13."""
